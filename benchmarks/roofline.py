@@ -1,8 +1,10 @@
 """Roofline analysis (spec: ROOFLINE ANALYSIS) — per (arch x shape x mesh):
 
-  compute term    = FLOPs / (chips * 197e12)
-  memory term     = HBM_bytes / (chips * 819e9)
-  collective term = collective_bytes_per_chip / 50e9
+  compute term    = FLOPs / (chips * peak FLOP/s)
+  memory term     = HBM_bytes / (chips * HBM bytes/s)
+  collective term = collective_bytes_per_chip / ICI bytes/s per link
+
+with the peaks of the target chip, looked up by `device_kind` in `PEAKS`.
 
 FLOP/byte volumes come from the validated analytic cost model
 (benchmarks/cost_model.py — see its docstring for why compiled cost_analysis
@@ -28,9 +30,23 @@ from repro.configs.base import ALL_SHAPES, ShapeConfig
 
 from benchmarks import cost_model
 
-PEAK_FLOPS = 197e12      # bf16 / chip (TPU v5e)
-HBM_BW = 819e9           # bytes/s / chip
-ICI_BW = 50e9            # bytes/s / link
+#: Published per-chip peaks, keyed by `jax.devices()[i].device_kind`.
+#: TPU v5e (Google Cloud documentation, "TPU v5e"): 197 TFLOP/s bf16,
+#: 819 GB/s HBM, 1,600 Gbit/s of chip-to-chip interconnect over 4 links.
+PEAKS = {
+    "TPU v5 lite": dict(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+#: The chip the production dry-run mesh describes (16x16 v5e pods).
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+  """Peak FLOP/s and bytes/s of one chip; an unknown kind is an error."""
+  if device_kind not in PEAKS:
+    raise ValueError(f"no published peaks for device kind {device_kind!r}; "
+                     f"known: {sorted(PEAKS)}")
+  return PEAKS[device_kind]
 
 RESULTS_DIR = os.path.join(os.path.dirname(__file__), "results", "dryrun")
 
@@ -65,10 +81,11 @@ def analyze_cell(arch: str, shape: ShapeConfig, chips: int = 256,
   if not pq:
     cfg = dataclasses.replace(cfg, pq_enabled=False)
   costs = cost_model.cell_costs(cfg, shape, n_data, n_model)
+  peak = peaks(TARGET_KIND)
 
-  t_compute = costs["flops"] / (chips * PEAK_FLOPS)
-  t_memory = costs["hbm_bytes"] / (chips * HBM_BW)
-  t_collective = costs["collective_bytes_per_chip"] / ICI_BW
+  t_compute = costs["flops"] / (chips * peak["flops"])
+  t_memory = costs["hbm_bytes"] / (chips * peak["hbm_bw"])
+  t_collective = costs["collective_bytes_per_chip"] / peak["ici_bw"]
   terms = {"compute": t_compute, "memory": t_memory,
            "collective": t_collective}
   dominant = max(terms, key=terms.get)
@@ -76,8 +93,8 @@ def analyze_cell(arch: str, shape: ShapeConfig, chips: int = 256,
 
   uf = useful_flops(cfg, shape)
   ub = useful_bytes(cfg, shape)
-  mfu = uf / (chips * PEAK_FLOPS * t_step) if t_step else 0.0
-  mbu = ub / (chips * HBM_BW * t_step) if t_step else 0.0
+  mfu = uf / (chips * peak["flops"] * t_step) if t_step else 0.0
+  mbu = ub / (chips * peak["hbm_bw"] * t_step) if t_step else 0.0
   headline = mfu if dominant == "compute" else (
       mbu if dominant == "memory" else max(mfu, mbu))
 

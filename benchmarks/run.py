@@ -43,6 +43,8 @@ import subprocess
 import sys
 import time
 
+from repro.common import compile_cache
+
 
 def run_csv() -> int:
   from benchmarks import (
@@ -456,7 +458,9 @@ def run_mesh(arch: str = "tinyllama-1.1b", sizes=(1, 2, 4)) -> dict:
   speedup — the scaling claim needs real devices; the byte column is the
   capacity-wall figure: heads-mode pool bytes per shard drop ~1/N.
   """
-  out = {"devices_forced": 8, "cache_layout": "paged", "scheduler": "paged",
+  # the probes run on forced CPU devices whatever this process runs on
+  out = {"platform": "cpu", "devices_forced": 8,
+         "cache_layout": "paged", "scheduler": "paged",
          "batch": 2, "prompt_len": 32, "gen": 12, "sizes": list(sizes),
          "policies": {}}
   root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -802,6 +806,7 @@ def run_shard_recovery(arch: str = "tinyllama-1.1b", seed: int = 3) -> dict:
   none_c, mirror_c = cells["none"], cells["host-mirror"]
   tokens_ok = none_c["tokens"] == mirror_c["tokens"]
   out = {
+      "platform": "cpu",       # forced CPU devices, like run_mesh's probes
       "devices_forced": 8, "mesh_model": 4, "mode": "heads", "seed": seed,
       "tokens_identical": tokens_ok,
       "none": {k: none_c[k] for k in
@@ -922,6 +927,7 @@ def main() -> None:
   ap.add_argument("--prompt-len", type=int, default=64)
   ap.add_argument("--gen", type=int, default=16)
   args = ap.parse_args()
+  compile_cache.enable()
   if args.json:
     sys.exit(run_serve_json(args.out, arch=args.arch, batch=args.batch,
                             prompt_len=args.prompt_len, gen=args.gen))

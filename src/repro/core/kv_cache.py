@@ -271,7 +271,8 @@ def packed_exact_cache_append_and_attend(
     scale: float,
     bits: int,
     use_kernel: bool = False,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> Tuple[Array, PackedExactLayerCache]:
   """Dense-storage packed decode step: quantize-insert the new row, then
   attend over the dequantized store (flash-decode kernel or masked XLA)."""
@@ -312,7 +313,7 @@ def packed_exact_cache_paged_step(
     length: Array,
     scale: float,
     bits: int,
-    interpret: bool = True,
+    interpret: bool,
 ):
   """Block-table-native packed decode step: quantize the new row, write its
   codes + headers into the mapped pool block, attend in place through the
@@ -723,7 +724,7 @@ def pq_cache_append_and_attend_kernel(
     length: Array,
     cfg: PQCacheConfig,
     scale: float,
-    interpret: bool = True,
+    interpret: bool,
 ) -> Tuple[Array, PQLayerCache]:
   """Dense-storage PQ decode step through the Pallas body kernel.
 
@@ -786,7 +787,7 @@ def pq_cache_paged_step(
     length: Array,
     cfg: PQCacheConfig,
     scale: float,
-    interpret: bool = True,
+    interpret: bool,
 ):
   """Block-table-native PQ decode step: pool read in place, one row written.
 
@@ -836,7 +837,7 @@ def exact_cache_append_and_attend_kernel(
     v_new: Array,
     length: Array,
     scale: float,
-    interpret: bool = True,
+    interpret: bool,
 ) -> Tuple[Array, ExactLayerCache]:
   """Dense-storage exact decode step through the flash-decode kernel."""
   b, hq, d = q.shape
@@ -860,7 +861,7 @@ def exact_cache_paged_step(
     v_new: Array,
     length: Array,
     scale: float,
-    interpret: bool = True,
+    interpret: bool,
 ):
   """Block-table-native exact decode step: insert one row, attend in place."""
   b, hq, d = q.shape

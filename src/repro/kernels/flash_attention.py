@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -80,7 +78,8 @@ def flash_attention_kernel(
     causal: bool = True,
     blk_q: int = 512,
     blk_k: int = 512,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
   b, hq, n, d = q.shape
   hkv = k.shape[1]
@@ -106,7 +105,7 @@ def flash_attention_kernel(
           pltpu.VMEM((blk_q, 1), jnp.float32),
           pltpu.VMEM((blk_q, 1), jnp.float32),
       ],
-      compiler_params=_CompilerParams(
+      compiler_params=pltpu.CompilerParams(
           dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
       ),
       interpret=interpret,

@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 
 def _assign_kernel(x_ref, c_ref, out_ref, *, blk: int):
   """x_ref (1, blk, dsub); c_ref (1, K, dsub); out_ref (1, blk) int32."""
@@ -40,7 +38,8 @@ def kmeans_assign_kernel(
     x: jax.Array,          # (m, N, dsub)
     centroids: jax.Array,  # (m, K, dsub)
     blk: int = 1024,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
   """Nearest-centroid ids (m, N) int32."""
   m, n, dsub = x.shape
@@ -56,7 +55,7 @@ def kmeans_assign_kernel(
       ],
       out_specs=pl.BlockSpec((1, blk), lambda mi, j: (mi, j)),
       out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
-      compiler_params=_CompilerParams(
+      compiler_params=pltpu.CompilerParams(
           dimension_semantics=("parallel", "arbitrary"),
       ),
       interpret=interpret,

@@ -38,6 +38,21 @@ def decode_block(n: int, preferred: int = 512) -> int:
   return max(blk, 1)
 
 
+def _codebook_lanes(cb: jax.Array) -> jax.Array:
+  """(B, H, m, K, dsub) codebook -> (B*H, dsub, m, K) f32, K on lanes."""
+  b, h, m, k_cent, dsub = cb.shape
+  return jnp.moveaxis(cb, -1, -3).reshape(b * h, dsub, m, k_cent).astype(
+      jnp.float32)
+
+
+def _split_stats(out, stats, b, h):
+  """Kernel (BH, g, d) out + (BH, g, 2) stats -> (out, max, denom) per
+  (B, H, g)."""
+  g, d = out.shape[1:]
+  stats = stats.reshape(b, h, g, 2)
+  return out.reshape(b, h, g, d), stats[..., 0], stats[..., 1]
+
+
 def pq_decode_attention(
     q: jax.Array,               # (B, H_kv, g, d)
     key_codebook: jax.Array,    # (B, H_kv, m, K, dsub)
@@ -52,24 +67,20 @@ def pq_decode_attention(
   """PQ body attention. Returns (out (B,H,g,d) f32, max (B,H,g), denom (B,H,g))."""
   b, h, g, d = q.shape
   bh = b * h
-  m, k_cent, dsub = key_codebook.shape[2:]
+  m = key_codebook.shape[2]
   n = key_indices.shape[2]
   if jnp.ndim(length) == 0:
     length = jnp.full((bh,), length, jnp.int32)
   else:
     length = length.reshape(bh).astype(jnp.int32)
-  vcbt = jnp.swapaxes(value_codebook, -1, -2)          # (B,H,m,dsub,K)
   out, stats = _pqd.pq_decode_attention_kernel(
       q.reshape(bh, g, d),
-      key_codebook.reshape(bh, m, k_cent, dsub).astype(jnp.float32),
-      vcbt.reshape(bh, m, dsub, k_cent).astype(jnp.float32),
+      _codebook_lanes(key_codebook), _codebook_lanes(value_codebook),
       key_indices.reshape(bh, n, m).astype(jnp.int32),
       value_indices.reshape(bh, n, m).astype(jnp.int32),
       length,
       scale=scale, blk=blk, interpret=_auto_interpret(interpret))
-  out = out.reshape(b, h, g, d)
-  stats = stats.reshape(b, h, 2, g)
-  return out, stats[:, :, 0], stats[:, :, 1]
+  return _split_stats(out, stats, b, h)
 
 
 def pq_decode_attention_paged(
@@ -91,20 +102,15 @@ def pq_decode_attention_paged(
   """
   b, h, g, d = q.shape
   bh = b * h
-  m, k_cent, dsub = key_codebook.shape[2:]
-  vcbt = jnp.swapaxes(value_codebook, -1, -2)          # (B,H,m,dsub,K)
   tables_bh = jnp.repeat(tables.astype(jnp.int32), h, axis=0)   # (BH, nb)
   length_bh = jnp.repeat(length.astype(jnp.int32), h, axis=0)
   out, stats = _pqd.pq_decode_attention_paged_kernel(
       q.reshape(bh, g, d),
-      key_codebook.reshape(bh, m, k_cent, dsub).astype(jnp.float32),
-      vcbt.reshape(bh, m, dsub, k_cent).astype(jnp.float32),
+      _codebook_lanes(key_codebook), _codebook_lanes(value_codebook),
       key_index_pool, value_index_pool,
       tables_bh, jnp.reshape(layer, (1,)).astype(jnp.int32), length_bh,
       scale=scale, interpret=_auto_interpret(interpret))
-  out = out.reshape(b, h, g, d)
-  stats = stats.reshape(b, h, 2, g)
-  return out, stats[:, :, 0], stats[:, :, 1]
+  return _split_stats(out, stats, b, h)
 
 
 def flash_decode(

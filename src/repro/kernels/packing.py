@@ -76,18 +76,61 @@ def quantize_rows(x: jax.Array, *, bits: int, group: int):
   return q.astype(jnp.uint8).reshape(lead + (d,)), scale, mn
 
 
+def f16_bits(header: jax.Array) -> jax.Array:
+  """f16 header -> its uint16 bit pattern (a free bitcast outside kernels).
+
+  The packed decode kernel takes its headers in this form: v5e's vector
+  unit has no f16 type, and Mosaic refuses f16 loads of header pages.
+  """
+  return jax.lax.bitcast_convert_type(header, jnp.uint16)
+
+
+def _f16_to_f32(bits: jax.Array) -> jax.Array:
+  """IEEE half bit patterns (16-bit ints) -> the f32 values they encode.
+
+  Exact for every half value (normals, subnormals, zeros, inf/nan), in
+  32-bit integer ops that lower anywhere, the kernel body included.
+  """
+  h = bits.astype(jnp.int32) & 0xFFFF
+  sign = (h >> 15) << 31
+  exp = (h >> 10) & 0x1F
+  mant = h & 0x3FF
+  wide = jnp.where(exp == 31, 0xFF, exp + 112)
+  normal = jax.lax.bitcast_convert_type(
+      sign | (wide << 23) | (mant << 13), jnp.float32)
+  sub = mant.astype(jnp.float32) * 2.0 ** -24
+  sub = jnp.where(sign != 0, -sub, sub)
+  return jnp.where(exp == 0, sub, normal)
+
+
+def _per_channel(header: jax.Array, d: int, group: int) -> jax.Array:
+  """Per-group f16 header (..., G), as f16 or its bits -> f32 (..., d).
+
+  Built from lane broadcasts and selects, not a reshape that splits the
+  channel axis into (G, group): Mosaic cannot lower that shape cast, and
+  this runs inside the packed decode kernel.
+  """
+  if header.dtype == jnp.float16:
+    header = f16_bits(header)
+  header = _f16_to_f32(header)
+  shape = header.shape[:-1] + (d,)
+  lane = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+  out = jnp.broadcast_to(header[..., :1], shape)
+  for gi in range(1, header.shape[-1]):
+    out = jnp.where(lane >= gi * group, header[..., gi:gi + 1], out)
+  return out
+
+
 def dequantize_rows(q: jax.Array, scale: jax.Array, mn: jax.Array,
                     *, group: int) -> jax.Array:
   """codes (..., d) int + per-group f16 params -> f32 (..., d).
 
   One formula for every consumer: f32(codes) * f32(scale) + f32(min).
+  The params may come as f16 or as their `f16_bits`.
   """
   d = q.shape[-1]
-  lead = q.shape[:-1]
-  qg = q.astype(jnp.float32).reshape(lead + (d // group, group))
-  x = (qg * scale.astype(jnp.float32)[..., None]
-       + mn.astype(jnp.float32)[..., None])
-  return x.reshape(lead + (d,))
+  return (q.astype(jnp.float32) * _per_channel(scale, d, group)
+          + _per_channel(mn, d, group))
 
 
 def pack_u4(q: jax.Array) -> jax.Array:
@@ -167,7 +210,7 @@ def _unpack_u4_kernel(p_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def unpack_u4_kernel(p: jax.Array, interpret: bool = True) -> jax.Array:
+def unpack_u4_kernel(p: jax.Array, interpret: bool) -> jax.Array:
   """Widen a (n, dp) uint8 nibble page to (n, 2*dp) int32 codes in VMEM.
 
   The unit-testable core of the packed decode kernels: everything they add

@@ -42,7 +42,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 from repro.kernels import packing
 
 NEG_INF = -1e30
@@ -119,7 +118,8 @@ def flash_decode_kernel(
     length: jax.Array,   # (BH,) int32 — valid tokens per row
     scale: float,
     blk: int = 512,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
   """Dense-storage flash decode: (BH, g, d) f32 attention outputs."""
   bhn, g, d = q.shape
@@ -130,7 +130,7 @@ def flash_decode_kernel(
       _flash_decode_kernel, scale=scale, blk=blk, n_blocks=n_blocks)
   return pl.pallas_call(
       kernel,
-      grid_spec=_compat.scalar_grid_spec(
+      grid_spec=pltpu.PrefetchScalarGridSpec(
           num_scalar_prefetch=1,
           grid=(bhn, n_blocks),
           in_specs=[
@@ -146,7 +146,7 @@ def flash_decode_kernel(
           ],
       ),
       out_shape=jax.ShapeDtypeStruct((bhn, g, d), jnp.float32),
-      compiler_params=_compat.compiler_params(
+      compiler_params=pltpu.CompilerParams(
           dimension_semantics=("arbitrary", "arbitrary")),
       interpret=interpret,
       name="flash_decode",
@@ -197,7 +197,7 @@ def paged_flash_decode_kernel(
     layer: jax.Array,      # (1,) int32
     length: jax.Array,     # (BH,) int32 — valid tokens per row
     scale: float,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
   """Block-table-native flash decode over pooled K/V: (BH, g, d) f32."""
   bhn, g, d = q.shape
@@ -214,7 +214,7 @@ def paged_flash_decode_kernel(
 
   return pl.pallas_call(
       kernel,
-      grid_spec=_compat.scalar_grid_spec(
+      grid_spec=pltpu.PrefetchScalarGridSpec(
           num_scalar_prefetch=3,
           grid=(bhn, n_blocks),
           in_specs=[
@@ -231,7 +231,7 @@ def paged_flash_decode_kernel(
           ],
       ),
       out_shape=jax.ShapeDtypeStruct((bhn, g, d), jnp.float32),
-      compiler_params=_compat.compiler_params(
+      compiler_params=pltpu.CompilerParams(
           dimension_semantics=("arbitrary", "arbitrary")),
       interpret=interpret,
       name="paged_flash_decode",
@@ -244,8 +244,8 @@ def _packed_paged_flash_decode_kernel(
     length_ref,            # (BH,) int32
     q_ref,                 # (1, g, d)
     kp_ref,                # (1, 1, 1, blk, dp) uint8 — packed K codes
-    ks_ref,                # (1, 1, 1, blk, G) f16 — K group scales
-    km_ref,                # (1, 1, 1, blk, G) f16 — K group minima
+    ks_ref,                # (1, 1, 1, blk, G) uint16 — K group scales (f16 bits)
+    km_ref,                # (1, 1, 1, blk, G) uint16 — K group minima (f16 bits)
     vp_ref, vs_ref, vm_ref,
     out_ref,               # (1, g, d) f32
     acc_ref, m_ref, l_ref,
@@ -293,7 +293,7 @@ def packed_paged_flash_decode_kernel(
     length: jax.Array,     # (BH,) int32 — valid tokens per row
     scale: float,
     bits: int,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
   """Block-table-native flash decode over *packed* pooled K/V.
 
@@ -319,7 +319,7 @@ def packed_paged_flash_decode_kernel(
 
   return pl.pallas_call(
       kernel,
-      grid_spec=_compat.scalar_grid_spec(
+      grid_spec=pltpu.PrefetchScalarGridSpec(
           num_scalar_prefetch=3,
           grid=(bhn, n_blocks),
           in_specs=[
@@ -336,9 +336,10 @@ def packed_paged_flash_decode_kernel(
           ],
       ),
       out_shape=jax.ShapeDtypeStruct((bhn, g, d), jnp.float32),
-      compiler_params=_compat.compiler_params(
+      compiler_params=pltpu.CompilerParams(
           dimension_semantics=("arbitrary", "arbitrary")),
       interpret=interpret,
       name="packed_paged_flash_decode",
-  )(tables, layer, length, q, k_pack, k_scale, k_min,
-    v_pack, v_scale, v_min)
+  )(tables, layer, length, q, k_pack, packing.f16_bits(k_scale),
+    packing.f16_bits(k_min), v_pack, packing.f16_bits(v_scale),
+    packing.f16_bits(v_min))

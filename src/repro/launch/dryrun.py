@@ -20,7 +20,6 @@ import traceback
 
 import jax
 
-from repro.common import compat
 from repro.configs import ARCHS, get_arch
 from repro.configs.base import ALL_SHAPES, ShapeConfig, smoke_shape
 from repro.launch import steps as steps_lib
@@ -109,7 +108,7 @@ def run_cell(arch: str, shape: ShapeConfig, multi_pod: bool,
         for k in ("temp_size_in_bytes", "argument_size_in_bytes",
                   "output_size_in_bytes", "generated_code_size_in_bytes")
         if hasattr(mem, k)}
-    cost = compat.normalize_cost_analysis(compiled.cost_analysis())
+    cost = compiled.cost_analysis()
     rec["cost"] = {k: float(v) for k, v in cost.items()
                    if isinstance(v, (int, float))}
     hlo = compiled.as_text()

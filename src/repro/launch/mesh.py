@@ -7,12 +7,20 @@ XLA_FLAGS=--xla_force_host_platform_device_count=512 BEFORE any jax import.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+  """`jax.make_mesh` with Auto axes: the programs place data through
+  `NamedSharding` specs and let XLA propagate the rest, which the Explicit
+  axes `make_mesh` builds by default would reject at the first gather."""
+  return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
   shape = (2, 16, 16) if multi_pod else (16, 16)
   axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-  return jax.make_mesh(shape, axes)
+  return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(model: int = 1, data: int | None = None):
@@ -44,7 +52,7 @@ def make_local_mesh(model: int = 1, data: int | None = None):
         f"devices but {n} exist; axis sizes must tile the device count "
         f"exactly — adjust --mesh-model (and the data axis) so "
         f"data * model == {n}")
-  return jax.make_mesh((data, model), ("data", "model"))
+  return _auto_mesh((data, model), ("data", "model"))
 
 
 def model_axis_size(mesh) -> int:

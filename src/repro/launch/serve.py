@@ -58,6 +58,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.common import compile_cache
 from repro.common.timing import Stopwatch, latency_percentiles_ms
 from repro.configs import get_arch
 from repro.configs.base import ShapeConfig
@@ -599,6 +600,7 @@ def make_parser() -> argparse.ArgumentParser:
 def main():
   ap = make_parser()
   args = ap.parse_args()
+  compile_cache.enable()
   # --no-pq is an alias for --cache-policy exact; refuse a conflicting mix
   # rather than silently measuring the wrong policy
   if args.no_pq:

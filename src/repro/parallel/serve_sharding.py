@@ -51,7 +51,6 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.parallel.sharding import MODEL_AXIS
@@ -231,9 +230,9 @@ def wrap_decode(decode_fn, plan: ShardPlan, storage_example: Any):
   `storage_pspec` (head-partitioned pools in heads mode, replicated in seq
   mode).  The body runs the *unmodified* program — the attention seam reads
   the activated plan and does the per-shard slice / ordered all_gather (or
-  split-K stats merge), so logits leave the body replicated.  check_rep is
+  split-K stats merge), so logits leave the body replicated.  check_vma is
   off: the replication of post-all_gather values is by construction, not
-  provable by the rep checker.
+  provable by the varying-axes checker.
   """
   st_specs = storage_pspecs(plan, storage_example)
 
@@ -241,11 +240,11 @@ def wrap_decode(decode_fn, plan: ShardPlan, storage_example: Any):
     with activate(plan):
       return decode_fn(params, cur, storage, tables, lengths)
 
-  return shard_map(
-      body, plan.mesh,
+  return jax.shard_map(
+      body, mesh=plan.mesh,
       in_specs=(P(), P(), st_specs, P(), P()),
       out_specs=(P(), st_specs),
-      check_rep=False)
+      check_vma=False)
 
 
 # ---------------------------------------------------------------------------

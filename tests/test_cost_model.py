@@ -13,7 +13,6 @@ import jax
 import pytest
 
 from benchmarks import cost_model
-from repro.common import compat
 from repro.configs import get_arch
 from repro.configs.base import ShapeConfig
 from repro.launch import steps as steps_lib
@@ -24,7 +23,7 @@ def _compiled_flops(cfg, shape):
   with mesh:
     progs = steps_lib.build_programs(cfg, shape, mesh, donate=False)
     compiled = progs.fn.lower(*progs.abstract_inputs).compile()
-    ca = compat.normalize_cost_analysis(compiled.cost_analysis())
+    ca = compiled.cost_analysis()
     return float(ca.get("flops", 0.0))
 
 

@@ -25,6 +25,9 @@ from repro.launch.engine import ServeEngine
 #: f16 relative rounding slack: scale/min are stored f16, so reconstruction
 #: error exceeds the ideal half-step by at most ~2^-11 of the group magnitude.
 F16_EPS = 2 ** -11
+#: f16 absolute rounding slack below the normal range (|v| < 2^-14): a
+#: subnormal half rounds to a multiple of 2^-24, off by at most 2^-25.
+F16_SUB_HALF_ULP = 2 ** -25
 
 
 def _spec(**kw):
@@ -90,8 +93,11 @@ def test_quantize_roundtrip_error_half_step(seed, bits, d, mag):
   absmax = np.abs(np.asarray(x)).reshape(3, d // group, group).max(-1)
   step = np.asarray(scale, np.float32)
   # half a step, plus the f16 rounding of scale (amplified by up to qmax
-  # codes) and of min
-  tol = 0.5 * step + F16_EPS * (step * ((1 << bits) - 1) + absmax) + 1e-12
+  # codes) and of min; each rounding is relative for normal halves and
+  # absolute for subnormal ones (small-magnitude q8 scales land there)
+  qmax = (1 << bits) - 1
+  tol = (0.5 * step + F16_EPS * (step * qmax + absmax)
+         + F16_SUB_HALF_ULP * (qmax + 1) + 1e-12)
   assert (err.max(-1) <= tol).all(), (err.max(), tol.min())
 
 
@@ -157,7 +163,8 @@ def test_packed_paged_kernel_matches_dense_xla_attend(rng):
 
   cache = kvc.packed_exact_cache_prefill(k, v, capacity, bits)
   want, _ = kvc.packed_exact_cache_append_and_attend(
-      cache, q, k_new, v_new, lengths, scale, bits, use_kernel=False)
+      cache, q, k_new, v_new, lengths, scale, bits, use_kernel=False,
+      interpret=False)
 
   # scatter the same dense store into pool blocks (pool id 0 = null block)
   tables = jnp.asarray([[1, 2, 3], [4, 5, 6]], jnp.int32)
@@ -194,7 +201,8 @@ def test_packed_paged_kernel_matches_dense_xla_attend(rng):
   err_q4 = float(jnp.abs(got - fp32).max())
   cache8 = kvc.packed_exact_cache_prefill(k, v, capacity, 8)
   got8, _ = kvc.packed_exact_cache_append_and_attend(
-      cache8, q, k_new, v_new, lengths, scale, 8, use_kernel=False)
+      cache8, q, k_new, v_new, lengths, scale, 8, use_kernel=False,
+      interpret=False)
   err_q8 = float(jnp.abs(got8 - fp32).max())
   assert 0 < err_q4 < 0.25, err_q4
   assert 0 < err_q8 < err_q4 / 4, (err_q8, err_q4)

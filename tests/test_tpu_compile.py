@@ -1,0 +1,106 @@
+"""Ahead-of-time compiles of the serve-path decode kernels for a TPU v5e.
+
+Nothing runs: each kernel is lowered and compiled by the installed TPU
+compiler for a described (not attached) v5e, at tinyllama-1.1b's full
+widths — g=8 query heads per kv head, head dim 64, PQ m=32 / K=512
+(dsub=2), 16-token pool blocks over 22 layers.  Interpret mode cannot show
+what these do: Mosaic refuses shape casts, gathers and loads it does not
+lower, and fast-memory budgets it cannot meet.
+
+The topology is described inside a module fixture, never at import: only
+one process may load the TPU library, so the worker that runs this file
+loads it and the others never try.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import paged_flash_decode as pfd
+from repro.kernels import pq_decode as pqd
+
+BH, G, D, M, K, DSUB = 16, 8, 64, 32, 512, 2   # 4 requests x 4 kv heads
+LAYERS, HEADS, BLOCK = 22, 4, 16
+POOL = 4 * 65 + 1                                # 4 x 1040-token tables + trash
+NB = 65
+SCALE = D ** -0.5
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+  from jax.experimental import topologies
+  from jax.experimental.compilation_cache import compilation_cache
+  try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+  except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+    pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+  # a described device's programs can be written to the persistent cache
+  # but never read back; keep them out of it
+  enabled = jax.config.jax_enable_compilation_cache
+  jax.config.update("jax_enable_compilation_cache", False)
+  compilation_cache.reset_cache()
+  yield SingleDeviceSharding(topo.devices[0])
+  jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _pq_dense(arg):
+  n = 512                                        # ServeRun body, blk 512
+  return pqd.pq_decode_attention_kernel.lower(
+      arg((BH, G, D), jnp.bfloat16), arg((BH, DSUB, M, K), jnp.float32),
+      arg((BH, DSUB, M, K), jnp.float32), arg((BH, n, M), jnp.int32),
+      arg((BH, n, M), jnp.int32), arg((BH,), jnp.int32),
+      scale=SCALE, blk=n, interpret=False)
+
+
+def _pq_paged(arg):
+  return pqd.pq_decode_attention_paged_kernel.lower(
+      arg((BH, G, D), jnp.bfloat16), arg((BH, DSUB, M, K), jnp.float32),
+      arg((BH, DSUB, M, K), jnp.float32),
+      arg((POOL, LAYERS, HEADS, BLOCK, M), jnp.int16),
+      arg((POOL, LAYERS, HEADS, BLOCK, M), jnp.int16),
+      arg((BH, NB), jnp.int32), arg((1,), jnp.int32), arg((BH,), jnp.int32),
+      scale=SCALE, interpret=False)
+
+
+def _flash(arg):
+  n = 1024
+  return pfd.flash_decode_kernel.lower(
+      arg((BH, G, D), jnp.bfloat16), arg((BH, n, D), jnp.bfloat16),
+      arg((BH, n, D), jnp.bfloat16), arg((BH,), jnp.int32),
+      scale=SCALE, blk=512, interpret=False)
+
+
+def _paged_flash(arg):
+  return pfd.paged_flash_decode_kernel.lower(
+      arg((BH, G, D), jnp.bfloat16),
+      arg((POOL, LAYERS, HEADS, BLOCK, D), jnp.bfloat16),
+      arg((POOL, LAYERS, HEADS, BLOCK, D), jnp.bfloat16),
+      arg((BH, NB), jnp.int32), arg((1,), jnp.int32), arg((BH,), jnp.int32),
+      scale=SCALE, interpret=False)
+
+
+def _packed(bits):
+  def lower(arg):
+    codes = arg((POOL, LAYERS, HEADS, BLOCK, D * bits // 8), jnp.uint8)
+    header = arg((POOL, LAYERS, HEADS, BLOCK, D // 32), jnp.float16)
+    return pfd.packed_paged_flash_decode_kernel.lower(
+        arg((BH, G, D), jnp.bfloat16), codes, header, header, codes, header,
+        header, arg((BH, NB), jnp.int32), arg((1,), jnp.int32),
+        arg((BH,), jnp.int32), scale=SCALE, bits=bits, interpret=False)
+  return lower
+
+
+@pytest.mark.parametrize("lower", [
+    pytest.param(_pq_dense, id="pq_decode_attention_kernel"),
+    pytest.param(_pq_paged, id="pq_decode_attention_paged_kernel"),
+    pytest.param(_flash, id="flash_decode_kernel"),
+    pytest.param(_paged_flash, id="paged_flash_decode_kernel"),
+    pytest.param(_packed(4), id="packed_paged_flash_decode_kernel-q4"),
+    pytest.param(_packed(8), id="packed_paged_flash_decode_kernel-q8"),
+])
+def test_decode_kernel_compiles_for_v5e(one_chip, lower):
+  def arg(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+  compiled = lower(arg).compile()
+  assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
