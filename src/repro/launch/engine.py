@@ -63,7 +63,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import time
 import warnings
 from typing import Any, List, Optional, Sequence, Tuple
 
@@ -182,9 +181,10 @@ class EngineStats:
   prefill_tokens: int = 0        # prompt tokens actually prefilled (computed)
   forked_blocks: int = 0         # copy-on-write forks of shared blocks
   dedup_bytes: int = 0           # peak bytes saved by multi-mapped blocks
-  # wall-clock per batched decode step (launch -> next-token sync), the
-  # distribution CI's p99 regression guard watches.  Bounded: a long-lived
-  # engine keeps the most recent window of samples, not its whole history
+  # wall-clock per batched decode step (launch -> next-token sync: the
+  # `engine.decode` span), the distribution CI's p99 regression guard
+  # watches.  Bounded: a long-lived engine keeps the most recent window of
+  # samples, not its whole history
   decode_step_s: collections.deque = dataclasses.field(
       default_factory=lambda: collections.deque(maxlen=4096), repr=False)
   # queue gauges the workload harness reads: depth sampled once per step(),
@@ -425,8 +425,10 @@ class ServeEngine:
       # mesh device once instead of letting GSPMD re-broadcast per program
       params = ssh.replicate(params, self.shard_plan)
     self.params = params
-    self._prefill = jax.jit(
-        lambda p, t, ln: self.model.prefill(p, t, None, lengths=ln))
+
+    def prefill(p, t, ln):
+      return self.model.prefill(p, t, None, lengths=ln)
+    self._prefill = jax.jit(prefill)
     # physical cache storage + its compiled admit/decode programs
     self.prefix_cache = (cfg.prefix_cache if prefix_cache is None
                          else bool(prefix_cache))
@@ -616,33 +618,52 @@ class ServeEngine:
 
   def step(self) -> List[RequestHandle]:
     """Admit queued requests into free slots, run one batched decode step,
-    and return the requests that finished this step."""
-    self.stats.queue_depth_samples.append(len(self._queue))
-    self._shard_fault_gate()
-    finished = self._enforce_slo() if self.slo_enforce else []
-    finished.extend(self._admit())
-    if self.active_count == 0:
-      self._step_no += 1
-      self.stats.steps += 1
-      self._sync_clock_stats()
+    and return the requests that finished this step.
+
+    Spans (`repro.common.timing`): `engine.step` around the call, holding
+    `engine.admit` (with an `engine.prefill` per admission),
+    `engine.blocks`, `engine.decode` (`decode.launch`: stage the inputs
+    and enqueue the program; `decode.wait`: the host blocked on the
+    step's tokens) and `engine.commit`."""
+    with timing.span("engine.step", step=self._step_no):
+      self.stats.queue_depth_samples.append(len(self._queue))
+      self._shard_fault_gate()
+      finished = self._enforce_slo() if self.slo_enforce else []
+      with timing.span("engine.admit"):
+        finished.extend(self._admit())
+      if self.active_count == 0:
+        self._step_no += 1
+        self.stats.steps += 1
+        self._sync_clock_stats()
+        return finished
+
+      # every active row grows by one token this step; secure its block
+      # first (may preempt-and-requeue under the paged scheduler, or shed
+      # expired lowest-priority work under SLO enforcement)
+      with timing.span("engine.blocks"):
+        self._ensure_blocks(finished)
+      if self.active_count == 0:          # everything preempted back to queue
+        self._step_no += 1
+        self.stats.steps += 1
+        self._sync_clock_stats()
+        return finished
+
+      self._decode_fault_gate()
+      with timing.span("engine.decode") as dec:
+        with timing.span("decode.launch"):
+          logits = self.layout.decode(self.params, self._cur, self._lengths)
+        # np.asarray blocks on the device result: the span runs launch->sync
+        with timing.span("decode.wait"):
+          next_tok = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+      self.stats.decode_step_s.append(dec.seconds)
+      with timing.span("engine.commit"):
+        self._commit(next_tok, finished)
       return finished
 
-    # every active row grows by one token this step; secure its block first
-    # (may preempt-and-requeue under the paged scheduler, or shed expired
-    # lowest-priority work under SLO enforcement)
-    self._ensure_blocks(finished)
-    if self.active_count == 0:            # everything preempted back to queue
-      self._step_no += 1
-      self.stats.steps += 1
-      self._sync_clock_stats()
-      return finished
-
-    self._decode_fault_gate()
-    t0 = time.perf_counter()
-    logits = self.layout.decode(self.params, self._cur, self._lengths)
-    # np.asarray blocks on the device result: the sample spans launch->sync
-    next_tok = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-    self.stats.decode_step_s.append(time.perf_counter() - t0)
+  def _commit(self, next_tok: np.ndarray,
+              finished: List[RequestHandle]) -> None:
+    """Fold one decode step's tokens into the requests: append, finish,
+    reclaim ring-retired blocks, refresh mirrors, start fetch-ahead."""
     if self.clock is not None:
       self.clock.advance(self.clock.decode_step_s)
     self.stats.decode_steps += 1
@@ -669,7 +690,6 @@ class ServeEngine:
     self._step_no += 1
     self.stats.steps += 1
     self._sync_clock_stats()
-    return finished
 
   def run_to_completion(self, max_steps: int = 10_000) -> List[RequestHandle]:
     """Drive `step()` until queue and slots drain; returns finish order."""
@@ -802,7 +822,8 @@ class ServeEngine:
             self._step_no - req.submitted_step)
       if self.clock is not None and req.admit_s is None:
         req.admit_s = self.clock.now   # queueing delay ends; prefill starts
-      first = self._prefill_into(slot, req, plan)
+      with timing.span("engine.prefill", rid=req.rid):
+        first = self._prefill_into(slot, req, plan)
       req.slot = slot
       req.admitted_step = self._step_no
       req.tokens.append(first)

@@ -66,17 +66,22 @@ class Model:
   # -------------------------------------------------------------------------
   # embedding / frontend stubs
   # -------------------------------------------------------------------------
+  # named scopes (`embed`, per layer `qkv` / `cache.<policy>` / `mlp`,
+  # `logits`) tag the compiled programs' op metadata, so a profile's device
+  # ops trace back to the layer boundary that emitted them
   def _embed(self, params, tokens: Array, modal: Optional[Array]) -> Array:
-    x = layers.embed_lookup(params["embed"], tokens)
-    if self.cfg.frontend == "audio_frames" and modal is not None:
-      # EnCodec frame-embedding stub: precomputed (B, S, D) added to tokens
-      x = x + modal.astype(x.dtype)
+    with jax.named_scope("embed"):
+      x = layers.embed_lookup(params["embed"], tokens)
+      if self.cfg.frontend == "audio_frames" and modal is not None:
+        # EnCodec frame-embedding stub: precomputed (B, S, D) added to tokens
+        x = x + modal.astype(x.dtype)
     return x
 
   def _logits(self, params, x: Array) -> Array:
-    x = layers.rmsnorm(params["final_norm"], x, self.cfg.norm_eps)
-    return jnp.einsum("...d,dv->...v", x,
-                      layers.wv(params["lm_head"], x.dtype))
+    with jax.named_scope("logits"):
+      x = layers.rmsnorm(params["final_norm"], x, self.cfg.norm_eps)
+      return jnp.einsum("...d,dv->...v", x,
+                        layers.wv(params["lm_head"], x.dtype))
 
   # -------------------------------------------------------------------------
   # training forward

@@ -45,30 +45,32 @@ def _attn_prefill(
   right-padded mixed batches (None -> full sequence).
   """
   scale = cfg.head_dim ** -0.5
-  q, k, v = layers.attention_qkv(p, x, positions, cfg.rope_theta)
+  with jax.named_scope("qkv"):
+    q, k, v = layers.attention_qkv(p, x, positions, cfg.rope_theta)
   attn = layers.chunked_attention(q, k, v, scale, causal=True,
                                   blk_q=cfg.attn_block, blk_k=cfg.attn_block)
   out = layers.attention_out(p, attn)
 
-  w = None
-  if policy.needs_weights:
-    # Eq. 1 weights per (batch, kv head): queries of the kv-group, averaged.
-    b, hq, s, hd = q.shape
-    hkv = k.shape[1]
-    g = hq // hkv
-    qg = q.reshape(b, hkv, g, s, hd)[:, :, 0]           # lead query head / group
-    t = policy.spec.recent
-    chunk = min(cfg.attn_block, s)
-    if lengths is None:
-      w = jax.vmap(jax.vmap(
-          lambda qq, kk: imp.attention_importance_weights(
-              qq, kk, scale, t=t, chunk=chunk)))(qg, k)  # (B, Hkv, S)
-    else:
-      w = jax.vmap(lambda qb, kb, ln: jax.vmap(
-          lambda qq, kk: imp.attention_importance_weights(
-              qq, kk, scale, t=t, chunk=chunk, length=ln))(qb, kb)
-      )(qg, k, lengths)
-  cache = policy.prefill(k, v, w, lengths)
+  with jax.named_scope(f"cache.{policy.name}"):
+    w = None
+    if policy.needs_weights:
+      # Eq. 1 weights per (batch, kv head): queries of the kv-group, averaged.
+      b, hq, s, hd = q.shape
+      hkv = k.shape[1]
+      g = hq // hkv
+      qg = q.reshape(b, hkv, g, s, hd)[:, :, 0]         # lead query head / group
+      t = policy.spec.recent
+      chunk = min(cfg.attn_block, s)
+      if lengths is None:
+        w = jax.vmap(jax.vmap(
+            lambda qq, kk: imp.attention_importance_weights(
+                qq, kk, scale, t=t, chunk=chunk)))(qg, k)  # (B, Hkv, S)
+      else:
+        w = jax.vmap(lambda qb, kb, ln: jax.vmap(
+            lambda qq, kk: imp.attention_importance_weights(
+                qq, kk, scale, t=t, chunk=chunk, length=ln))(qb, kb)
+        )(qg, k, lengths)
+    cache = policy.prefill(k, v, w, lengths)
   return out, cache
 
 
@@ -130,13 +132,14 @@ def dense_block_chunk(p: dict, x: Array, cache, positions: Array, cfg,
 
 def _attn_qkv_step(p: dict, x: Array, lengths: Array, cfg):
   """Single-token q/k/v projection + RoPE at each row's position."""
-  pos = lengths[:, None]                                 # (B, 1) RoPE positions
-  q = jnp.einsum("bsd,dhk->bshk", x, layers.wv(p["wq"], x.dtype))
-  k = jnp.einsum("bsd,dhk->bshk", x, layers.wv(p["wk"], x.dtype))
-  v = jnp.einsum("bsd,dhk->bshk", x, layers.wv(p["wv"], x.dtype))
-  q = layers.apply_rope(q, pos, cfg.rope_theta)[:, 0]    # (B, H, hd)
-  k = layers.apply_rope(k, pos, cfg.rope_theta)[:, 0]
-  return q, k, v[:, 0]
+  with jax.named_scope("qkv"):
+    pos = lengths[:, None]                               # (B, 1) RoPE positions
+    q = jnp.einsum("bsd,dhk->bshk", x, layers.wv(p["wq"], x.dtype))
+    k = jnp.einsum("bsd,dhk->bshk", x, layers.wv(p["wk"], x.dtype))
+    v = jnp.einsum("bsd,dhk->bshk", x, layers.wv(p["wv"], x.dtype))
+    q = layers.apply_rope(q, pos, cfg.rope_theta)[:, 0]  # (B, H, hd)
+    k = layers.apply_rope(k, pos, cfg.rope_theta)[:, 0]
+    return q, k, v[:, 0]
 
 
 def _attn_step(
@@ -155,15 +158,17 @@ def _attn_step(
   lengths = kvc.as_lengths(lengths, x.shape[0])
   q, k, v = _attn_qkv_step(p, x, lengths, cfg)
   plan = ssh.active_plan()
-  if plan is None:
-    attn, new_cache = policy.append_and_attend(cache, q, k, v, lengths)
-  elif plan.mode == "heads":
-    q_l, k_l, v_l = ssh.shard_attn_inputs(q, k, v, plan)
-    attn, new_cache = policy.append_and_attend(cache, q_l, k_l, v_l, lengths)
-    attn = ssh.gather_attn_outputs(attn, plan)
-  else:                                   # seq split-K (exact store only)
-    attn, new_cache = ssh.seq_append_and_attend(
-        cache, q, k, v, lengths, cfg.head_dim ** -0.5, plan)
+  with jax.named_scope(f"cache.{policy.name}"):
+    if plan is None:
+      attn, new_cache = policy.append_and_attend(cache, q, k, v, lengths)
+    elif plan.mode == "heads":
+      q_l, k_l, v_l = ssh.shard_attn_inputs(q, k, v, plan)
+      attn, new_cache = policy.append_and_attend(cache, q_l, k_l, v_l,
+                                                 lengths)
+      attn = ssh.gather_attn_outputs(attn, plan)
+    else:                                 # seq split-K (exact store only)
+      attn, new_cache = ssh.seq_append_and_attend(
+          cache, q, k, v, lengths, cfg.head_dim ** -0.5, plan)
   out = jnp.einsum("bhk,hkd->bd", attn.astype(x.dtype),
                    layers.wv(p["wo"], x.dtype))
   return out[:, None, :], new_cache
@@ -183,19 +188,20 @@ def _attn_step_paged(
   lengths = kvc.as_lengths(lengths, x.shape[0])
   q, k, v = _attn_qkv_step(p, x, lengths, cfg)
   plan = ssh.active_plan()
-  if plan is None:
-    attn, resident, pools = policy.append_and_attend_paged(
-        resident, pools, layer, tables, q, k, v, lengths)
-  else:
-    # heads mode only: the block-native kernels are H-shape-generic, so the
-    # same slice/attend/gather seam as `_attn_step` applies — each shard's
-    # kernel streams its own head-slice of the pool through the shared
-    # scalar-prefetched tables.  (Seq mode forces the dense program at
-    # dispatch resolution; see core.decode_dispatch.resolve_for_plan.)
-    q_l, k_l, v_l = ssh.shard_attn_inputs(q, k, v, plan)
-    attn, resident, pools = policy.append_and_attend_paged(
-        resident, pools, layer, tables, q_l, k_l, v_l, lengths)
-    attn = ssh.gather_attn_outputs(attn, plan)
+  with jax.named_scope(f"cache.{policy.name}"):
+    if plan is None:
+      attn, resident, pools = policy.append_and_attend_paged(
+          resident, pools, layer, tables, q, k, v, lengths)
+    else:
+      # heads mode only: the block-native kernels are H-shape-generic, so
+      # the same slice/attend/gather seam as `_attn_step` applies — each
+      # shard's kernel streams its own head-slice of the pool through the
+      # shared scalar-prefetched tables.  (Seq mode forces the dense program
+      # at dispatch resolution; see core.decode_dispatch.resolve_for_plan.)
+      q_l, k_l, v_l = ssh.shard_attn_inputs(q, k, v, plan)
+      attn, resident, pools = policy.append_and_attend_paged(
+          resident, pools, layer, tables, q_l, k_l, v_l, lengths)
+      attn = ssh.gather_attn_outputs(attn, plan)
   out = jnp.einsum("bhk,hkd->bd", attn.astype(x.dtype),
                    layers.wv(p["wo"], x.dtype))
   return out[:, None, :], resident, pools
@@ -233,7 +239,8 @@ def _ffn_apply(p: dict, x: Array, cfg) -> Tuple[Array, Array]:
                                cfg.capacity_factor,
                                a2a_quant=getattr(cfg, "moe_a2a_quant", False))
     return out, aux
-  return layers.mlp(p["mlp"], x), jnp.asarray(0.0, jnp.float32)
+  with jax.named_scope("mlp"):
+    return layers.mlp(p["mlp"], x), jnp.asarray(0.0, jnp.float32)
 
 
 def dense_block_forward(p: dict, x: Array, positions: Array, cfg
