@@ -4,6 +4,8 @@
                   intra-row indirection analogue); dense + block-table-native
                   (paged pool) variants
 - paged_flash_decode  exact-policy flash decode, dense + block-table-native
+                  (a grid step reads many pool pages of all kv heads of a
+                  row, only those below its length)
 - kmeans_assign   distance-calculation + cluster-assignment step of online k-means
 - flash_attention exact blockwise attention (prefill / baseline)
 

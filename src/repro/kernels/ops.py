@@ -148,15 +148,10 @@ def paged_flash_decode(
     interpret: Optional[bool] = None,
 ) -> jax.Array:
   """Block-table-native flash decode over pooled K/V (exact policy)."""
-  b, h, g, d = q.shape
-  bh = b * h
-  tables_bh = jnp.repeat(tables.astype(jnp.int32), h, axis=0)
-  length_bh = jnp.repeat(length.astype(jnp.int32), h, axis=0)
-  out = _pfd.paged_flash_decode_kernel(
-      q.reshape(bh, g, d), k_pool, v_pool, tables_bh,
-      jnp.reshape(layer, (1,)).astype(jnp.int32), length_bh,
+  return _pfd.paged_flash_decode_kernel(
+      q, k_pool, v_pool, tables.astype(jnp.int32),
+      jnp.reshape(layer, (1,)).astype(jnp.int32), length.astype(jnp.int32),
       scale=scale, interpret=_auto_interpret(interpret))
-  return out.reshape(b, h, g, d)
 
 
 def packed_paged_flash_decode(

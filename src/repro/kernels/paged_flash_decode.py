@@ -1,36 +1,58 @@
-"""Pallas TPU kernel: flash-decode attention for the exact policy, block-table
-native.
+"""Pallas TPU kernels: flash-decode attention for the exact policy, dense and
+block-table native.
 
-One decode step of exact attention — a (g, d) GQA query group against that kv
-head's cached K/V — as a flash-decoding scan over token blocks with running
-(max, denom) in VMEM scratch.  Two entry points share one block body:
+One decode step of exact attention — a (g, d) GQA query group against its
+kv head's cached K/V — as a flash-decoding scan over token blocks with a
+running (max, denom, acc) in VMEM scratch.
 
   ``flash_decode_kernel``        dense per-request K/V (BH, N, d) — the
-                                 contiguous-layout serve path;
+                                 contiguous-layout serve path; grid
+                                 (BH, N/blk), one (blk, d) block a step;
   ``paged_flash_decode_kernel``  *block-table-native*: K/V live in the paged
-                                 layout's physical pool (P+1, L, H, block, d)
-                                 and the sequence-block grid axis streams pool
-                                 block ``table[bh, j]`` of layer ``layer[0]``
-                                 via scalar-prefetched index maps.  The pool
-                                 is an ordinary pallas_call input — never
-                                 sliced, gathered, or densified in HBM; the
-                                 only HBM reads are the mapped blocks.
+                                 layout's physical pool (P+1, L, H, blk, d),
+                                 never sliced, gathered or densified in HBM;
+                                 the only HBM reads are the pages below each
+                                 row's length;
+  ``packed_paged_flash_decode_kernel``  the same over sub-byte packed pages,
+                                 one page of one kv head a step.
 
 This is the storage/compute cooperation LoL-PIM-style systems identify as the
 long-context decode bottleneck: the dense gather->decode->scatter round trip
 (2x the active KV through HBM per step) collapses to block reads plus the one
 inserted token row.
 
-Unallocated table entries point at the pool's trash block; their rows sit at
-positions >= the request's length and are masked like any ragged tail.
+``paged_flash_decode_kernel`` grid: (B, ceil(nb / pps)), both sequential
+("arbitrary").  A step covers ``pps`` consecutive table entries of one row
+for all H kv heads (q arrives (B, H, g, d)): scores (H, g, pps*blk) are one
+batched product, and the (H, g, .) f32 (max, denom, acc) scratch carries
+across the row's steps.  ``pps`` follows from the shapes
+(`pages_per_step`): about `STEP_TOKENS` tokens, at most nb, halved until the
+step fits `STEP_VMEM_BYTES`; 16 pages at 16-token pages, so a 4096-token
+table takes 16 steps a row, whatever the page size.  The last group may be
+ragged.  A group at or past a row's length fetches and computes nothing;
+the last live group fetches only its live pages, so pages past the length
+(and the trash block unallocated entries point at) are never read.  Rows
+past the length inside the last live page are masked, their values zeroed.
 
-Grid: (batch*kv_heads, token_blocks), both sequential ("arbitrary") so the
-(max, denom, acc) scratch carries across the token axis and is re-inited per
-bh row at @pl.when(j == 0).
+What is fetched, by head dim:
 
-VMEM budget per grid cell (g<=16, d=128, blk<=512, f32):
-  k/v blocks 2*(blk, d)  <= 0.5 MiB
-  acc (g, d) + s (g, blk) + m/l (g, 1)  << 0.1 MiB
+  d a multiple of 128  the pool stays in HBM (``memory_space=pl.ANY``); each
+                       live page ``pool.at[table[b, i], layer]``, one
+                       contiguous (H, blk, d) slice, is DMAed by hand into a
+                       (2, H, pps*blk, d) VMEM buffer, double-buffered: a
+                       step starts the next live group's pages (in this row
+                       or the next live one) before computing its own;
+  narrower heads       Mosaic refuses DMA slices narrower than a lane tile,
+                       so each page of the group is its own pipelined input
+                       (1, 1, H, blk, d).  An entry past the length maps to
+                       the row's last live page; the pipeline skips a fetch
+                       whose page is the one that input held a step before,
+                       so the trash block is never read and the row's dead
+                       groups fetch at most one group of repeats.
+
+VMEM per step (H=8, g=7, d=128, blk=16, pps=16, bf16 pool): page buffers
+2 x 2 x (8, 256, 128) bf16 = 2 MiB; f32 K/V working copies 2 MiB; scores,
+weights, (acc, m, l) < 0.2 MiB.
 """
 from __future__ import annotations
 
@@ -42,6 +64,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.common import timing
 from repro.kernels import packing
 
 NEG_INF = -1e30
@@ -153,36 +176,179 @@ def flash_decode_kernel(
   )(length, q, k, v)
 
 
+#: tokens one grid step of `paged_flash_decode_kernel` aims to cover: at
+#: yi-34b-exact's cell shapes on a v5e a call took 0.410 ms at 128, 0.325
+#: at 256 and 0.315 at 512 (twice the VMEM)
+STEP_TOKENS = 256
+#: fast memory one grid step's page buffers and f32 working set may take,
+#: half a v5e core's 16 MiB scoped default
+STEP_VMEM_BYTES = 8 * 2 ** 20
+#: lanes of a vreg: a pool page is fetched by hand only when its rows are
+#: a whole number of them (Mosaic refuses narrower DMA slices)
+LANES = 128
+
+
+def _step_vmem_bytes(pps: int, h: int, g: int, blk: int, d: int,
+                     itemsize: int) -> int:
+  t = pps * blk
+  buffers = 2 * 2 * h * t * d * itemsize     # K and V, two slots each
+  work = 2 * h * t * d * 4 + 3 * h * max(g, 8) * t * 4   # f32 K/V; s, p
+  return buffers + work
+
+
+def pages_per_step(nb: int, h: int, g: int, blk: int, d: int,
+                   itemsize: int) -> int:
+  """Pool pages one grid step fetches for every kv head of a row: about
+  `STEP_TOKENS` tokens, at most the table's `nb`, halved until the step
+  fits `STEP_VMEM_BYTES`.  `nb` need not be a multiple of it."""
+  pps = max(1, min(nb, STEP_TOKENS // blk))
+  while pps > 1 and _step_vmem_bytes(pps, h, g, blk, d,
+                                     itemsize) > STEP_VMEM_BYTES:
+    pps //= 2
+  return pps
+
+
+def _init_heads(acc_ref, m_ref, l_ref):
+  acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+  m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+  l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+
+
+def _accumulate_group(q, k, v, start, length, scale, acc_ref, m_ref, l_ref):
+  """One group of pages of flash decoding, every kv head at once.
+
+  q (H, g, d); k/v (H, t, d) holding tokens [start, start + t); rows at or
+  past `length` may hold anything, NaN included.
+  """
+  h, t = k.shape[0], k.shape[1]
+  dt = jnp.promote_types(q.dtype, k.dtype)
+  s = jax.lax.dot_general(
+      q.astype(dt), k.astype(dt),
+      dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+      preferred_element_type=jnp.float32) * scale        # (H, g, t)
+  valid = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2) < length
+  s = jnp.where(valid, s, NEG_INF)
+  m_prev = m_ref[...]
+  m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+  alpha = jnp.exp(m_prev - m_new)
+  p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+  l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+  m_ref[...] = m_new
+  # zero the rows past the length: 0 * NaN would reach the sum
+  v_rows = start + jax.lax.broadcasted_iota(jnp.int32, (h, t, 1), 1)
+  v = jnp.where(v_rows < length, v.astype(jnp.float32), 0.0)
+  acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
+      p, v, dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+      preferred_element_type=jnp.float32)                 # (H, g, d)
+
+
 def _paged_flash_decode_kernel(
-    tables_ref,            # (BH, nb) int32 — per-slot block tables
+    tables_ref,            # (B*nb,) int32 — row b's block table at b*nb
     layer_ref,             # (1,) int32
-    length_ref,            # (BH,) int32
-    q_ref,                 # (1, g, d)
-    k_ref,                 # (1, 1, 1, blk, d) — pool block tables[bh, j]
-    v_ref,                 # (1, 1, 1, blk, d)
-    out_ref,               # (1, g, d) f32
-    acc_ref, m_ref, l_ref,
-    *, scale: float, blk: int, n_blocks: int,
+    length_ref,            # (B,) int32 — valid tokens per row
+    q_ref,                 # (1, H, g, d)
+    k_hbm,                 # (P+1, L, H, blk, d) — the pool, left in HBM
+    v_hbm,
+    out_ref,               # (1, H, g, d) f32
+    k_buf,                 # (2, H, pps*blk, d) — two slots of fetched pages
+    v_buf,
+    sems,                  # DMA (2, 2): [K or V, slot]
+    state_ref,             # SMEM (2,): slot of this group; its DMAs started
+    acc_ref,               # (H, g, d) f32
+    m_ref, l_ref,          # (H, g, 1) f32
+    *, scale: float, blk: int, pps: int, nb: int,
 ):
-  bh = pl.program_id(0)
+  b = pl.program_id(0)
   j = pl.program_id(1)
-  g, d = q_ref.shape[1], q_ref.shape[2]
+  n_rows = pl.num_programs(0)
+
+  def live_pages(row):
+    return jnp.minimum(pl.cdiv(length_ref[row], blk), nb)
+
+  def for_each_page(row, group, slot, act):
+    """`act` on the K and V copies of each page of `group` below the row's
+    length: pages past it are never read."""
+    def body(i, carry):
+      pid = tables_ref[row * nb + group * pps + i]
+      rows = pl.ds(pl.multiple_of(i * blk, blk), blk)
+      for n, (pool, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+        act(pltpu.make_async_copy(pool.at[pid, layer_ref[0]],
+                                  buf.at[slot, :, rows], sems.at[n, slot]))
+      return carry
+    jax.lax.fori_loop(0, jnp.minimum(pps, live_pages(row) - group * pps),
+                      body, 0)
+
+  def next_live_group():
+    """(row, group) of the next grid step that fetches; row == n_rows if
+    none does."""
+    def next_row():
+      def skip(r, nxt):
+        return jnp.where((nxt == r) & (length_ref[r] <= 0), r + 1, nxt)
+      return jax.lax.fori_loop(b + 1, n_rows, skip, b + 1), 0
+    return jax.lax.cond((j + 1) * pps < live_pages(b),
+                        lambda: (b, j + 1), next_row)
+
+  @pl.when((b == 0) & (j == 0))
+  def _reset():
+    state_ref[0] = 0
+    state_ref[1] = 0
 
   @pl.when(j == 0)
   def _init():
-    _init_scratch(g, d, acc_ref, m_ref, l_ref)
+    _init_heads(acc_ref, m_ref, l_ref)
 
-  length = length_ref[bh]
-  pos = j * blk + jax.lax.broadcasted_iota(jnp.int32, (1, blk), 1)[0]
+  @pl.when(j * pps < live_pages(b))
+  def _group():
+    slot = state_ref[0]
 
-  @pl.when(j * blk < length)
-  def _block():
-    _accumulate_block(q_ref[0].astype(jnp.float32),
-                      k_ref[0, 0, 0].astype(jnp.float32),
-                      v_ref[0, 0, 0].astype(jnp.float32),
-                      pos < length, scale, acc_ref, m_ref, l_ref)
+    @pl.when(state_ref[1] == 0)
+    def _fetch():
+      for_each_page(b, j, slot, lambda cp: cp.start())
 
-  @pl.when(j == n_blocks - 1)
+    # start the next group's pages, in this row or the next live one,
+    # before computing this group
+    row, group = next_live_group()
+
+    @pl.when(row < n_rows)
+    def _prefetch():
+      for_each_page(row, group, 1 - slot, lambda cp: cp.start())
+
+    state_ref[0] = 1 - slot
+    state_ref[1] = (row < n_rows).astype(jnp.int32)
+    for_each_page(b, j, slot, lambda cp: cp.wait())
+    _accumulate_group(q_ref[0], k_buf[slot], v_buf[slot], j * pps * blk,
+                      length_ref[b], scale, acc_ref, m_ref, l_ref)
+
+  @pl.when(j == pl.num_programs(1) - 1)
+  def _done():
+    _finalize(out_ref, acc_ref, l_ref)
+
+
+def _paged_flash_decode_pipelined_kernel(
+    tables_ref, layer_ref, length_ref,
+    q_ref,                 # (1, H, g, d)
+    *refs,                 # pps K pages, pps V pages (1, 1, H, blk, d); out;
+                           # acc, m, l as above
+    scale: float, blk: int, pps: int,
+):
+  k_refs, v_refs = refs[:pps], refs[pps:2 * pps]
+  out_ref, acc_ref, m_ref, l_ref = refs[2 * pps:]
+  b = pl.program_id(0)
+  j = pl.program_id(1)
+  length = length_ref[b]
+
+  @pl.when(j == 0)
+  def _init():
+    _init_heads(acc_ref, m_ref, l_ref)
+
+  @pl.when(j * pps * blk < length)
+  def _group():
+    _accumulate_group(
+        q_ref[0], jnp.concatenate([r[0, 0] for r in k_refs], axis=1),
+        jnp.concatenate([r[0, 0] for r in v_refs], axis=1),
+        j * pps * blk, length, scale, acc_ref, m_ref, l_ref)
+
+  @pl.when(j == pl.num_programs(1) - 1)
   def _done():
     _finalize(out_ref, acc_ref, l_ref)
 
@@ -190,52 +356,68 @@ def _paged_flash_decode_kernel(
 @functools.partial(
     jax.jit, static_argnames=("scale", "interpret"))
 def paged_flash_decode_kernel(
-    q: jax.Array,          # (BH, g, d)
+    q: jax.Array,          # (B, H, g, d)
     k_pool: jax.Array,     # (P+1, L, H, blk, d)
     v_pool: jax.Array,     # (P+1, L, H, blk, d)
-    tables: jax.Array,     # (BH, nb) int32 — logical block j -> pool block
+    tables: jax.Array,     # (B, nb) int32 — logical block j -> pool block
     layer: jax.Array,      # (1,) int32
-    length: jax.Array,     # (BH,) int32 — valid tokens per row
+    length: jax.Array,     # (B,) int32 — valid tokens per row
     scale: float,
     interpret: bool,
 ) -> jax.Array:
-  """Block-table-native flash decode over pooled K/V: (BH, g, d) f32."""
-  bhn, g, d = q.shape
-  n_heads = k_pool.shape[2]
+  """Block-table-native flash decode over pooled K/V: (B, H, g, d) f32."""
+  n_rows, h, g, d = q.shape
   blk = k_pool.shape[3]
-  n_blocks = tables.shape[1]
-  kernel = functools.partial(
-      _paged_flash_decode_kernel, scale=scale, blk=blk, n_blocks=n_blocks)
-
-  def pool_spec():
-    return pl.BlockSpec(
-        (1, 1, 1, blk, d),
-        lambda bh, j, tbl, lyr, L: (tbl[bh, j], lyr[0], bh % n_heads, 0, 0))
-
+  if k_pool.shape[2] != h:
+    raise ValueError(f"q has {h} kv heads, the pool {k_pool.shape[2]}")
+  nb = tables.shape[1]
+  pps = pages_per_step(nb, h, g, blk, d, k_pool.dtype.itemsize)
+  grid = (n_rows, pl.cdiv(nb, pps))
+  by_hand = d % LANES == 0
+  with timing.span("kernel.paged_flash_decode", pps=pps, grid=grid,
+                   blk=blk, dma="manual" if by_hand else "pipelined"):
+    pass
+  row = pl.BlockSpec((1, h, g, d), lambda b, j, tbl, lyr, L: (b, 0, 0, 0))
+  heads = [pltpu.VMEM((h, g, d), jnp.float32),
+           pltpu.VMEM((h, g, 1), jnp.float32),
+           pltpu.VMEM((h, g, 1), jnp.float32)]
+  if by_hand:
+    kernel = functools.partial(
+        _paged_flash_decode_kernel, scale=scale, blk=blk, pps=pps, nb=nb)
+    pools = [pl.BlockSpec(memory_space=pl.ANY)] * 2
+    scratch = [pltpu.VMEM((2, h, pps * blk, d), k_pool.dtype),
+               pltpu.VMEM((2, h, pps * blk, d), v_pool.dtype),
+               pltpu.SemaphoreType.DMA((2, 2)),
+               pltpu.SMEM((2,), jnp.int32)] + heads
+    operands = (k_pool, v_pool)
+  else:
+    # one pipelined input per page of the group; an entry past the row's
+    # length maps to its last live page, never to the trash block
+    def page(i):
+      def index(b, j, tbl, lyr, L):
+        last = jnp.maximum(pl.cdiv(L[b], blk), 1) - 1
+        return tbl[b * nb + jnp.minimum(j * pps + i, last)], lyr[0], 0, 0, 0
+      return pl.BlockSpec((1, 1, h, blk, d), index)
+    kernel = functools.partial(
+        _paged_flash_decode_pipelined_kernel, scale=scale, blk=blk, pps=pps)
+    pools = [page(i) for i in range(pps)] * 2
+    scratch = heads
+    operands = (k_pool,) * pps + (v_pool,) * pps
   return pl.pallas_call(
       kernel,
       grid_spec=pltpu.PrefetchScalarGridSpec(
           num_scalar_prefetch=3,
-          grid=(bhn, n_blocks),
-          in_specs=[
-              pl.BlockSpec((1, g, d), lambda bh, j, tbl, lyr, L: (bh, 0, 0)),
-              pool_spec(),
-              pool_spec(),
-          ],
-          out_specs=pl.BlockSpec((1, g, d),
-                                 lambda bh, j, tbl, lyr, L: (bh, 0, 0)),
-          scratch_shapes=[
-              pltpu.VMEM((g, d), jnp.float32),
-              pltpu.VMEM((g, 1), jnp.float32),
-              pltpu.VMEM((g, 1), jnp.float32),
-          ],
+          grid=grid,
+          in_specs=[row] + pools,
+          out_specs=row,
+          scratch_shapes=scratch,
       ),
-      out_shape=jax.ShapeDtypeStruct((bhn, g, d), jnp.float32),
+      out_shape=jax.ShapeDtypeStruct((n_rows, h, g, d), jnp.float32),
       compiler_params=pltpu.CompilerParams(
           dimension_semantics=("arbitrary", "arbitrary")),
       interpret=interpret,
       name="paged_flash_decode",
-  )(tables, layer, length, q, k_pool, v_pool)
+  )(tables.reshape(-1), layer, length, q, *operands)
 
 
 def _packed_paged_flash_decode_kernel(

@@ -136,49 +136,78 @@ def test_paged_pq_kernel_matches_dense_kernel_and_oracle(
                              np.asarray(r_out), rtol=1e-4, atol=1e-4)
 
 
-@settings(max_examples=8, deadline=None)
-@given(
-    g=st.integers(1, 4),
-    d=st.sampled_from([8, 16, 32]),
-    blk=st.sampled_from([8, 16]),
-    nb=st.integers(2, 6),
-    seed=st.integers(0, 2**31 - 1),
-)
-def test_paged_flash_decode_matches_exact_oracle(g, d, blk, nb, seed):
-  """Paged flash decode == exact_decode_attention on the gathered view."""
+def _check_paged_flash_decode(g, d, blk, nb, h, lengths, seed):
+  """Paged flash decode == exact_decode_attention on the gathered view.
+
+  Every pool row past a row's length is NaN: the trash block that
+  unallocated table entries point at, and the tail of the last live page.
+  None of it may reach the sum (0 * NaN is NaN).
+  """
   rng = np.random.default_rng(seed)
-  b, h, layers = 2, 2, 2
+  lengths = np.asarray(lengths, np.int32)
+  b, layers = len(lengths), 2
   n = blk * nb
   pool_blocks = b * nb + 2
   trash = pool_blocks
   layer = int(rng.integers(0, layers))
   q = jnp.asarray(rng.normal(size=(b, h, g, d)), jnp.float32)
-  k_pool = jnp.asarray(
-      rng.normal(size=(pool_blocks + 1, layers, h, blk, d)), jnp.float32)
-  v_pool = jnp.asarray(
-      rng.normal(size=(pool_blocks + 1, layers, h, blk, d)), jnp.float32)
+  k_pool = rng.normal(size=(pool_blocks + 1, layers, h, blk, d))
+  v_pool = rng.normal(size=(pool_blocks + 1, layers, h, blk, d))
   tables = rng.permutation(pool_blocks)[:b * nb].reshape(b, nb).astype(
       np.int32)
-  lengths = rng.integers(1, n + 1, size=(b,)).astype(np.int32)
   for i in range(b):
-    for j in range(-(-int(lengths[i]) // blk), nb):
-      tables[i, j] = trash
+    live = -(-int(lengths[i]) // blk)
+    tables[i, live:] = trash
+    if lengths[i] % blk:
+      for pool in (k_pool, v_pool):
+        pool[tables[i, live - 1], :, :, lengths[i] % blk:] = np.nan
+  k_pool[trash] = v_pool[trash] = np.nan
   scale = 1 / np.sqrt(d)
-  out = ops.paged_flash_decode(
-      q, k_pool, v_pool, jnp.asarray(tables), jnp.asarray(layer),
-      jnp.asarray(lengths), scale)
+  out = np.asarray(ops.paged_flash_decode(
+      q, jnp.asarray(k_pool, jnp.float32), jnp.asarray(v_pool, jnp.float32),
+      jnp.asarray(tables), jnp.asarray(layer), jnp.asarray(lengths), scale))
+  assert np.isfinite(out).all()
   for i in range(b):
+    mask = np.arange(n) < lengths[i]
     for hh in range(h):
-      kd = np.concatenate([np.asarray(k_pool[tables[i, j], layer, hh])
-                           for j in range(nb)])
-      vd = np.concatenate([np.asarray(v_pool[tables[i, j], layer, hh])
-                           for j in range(nb)])
-      mask = np.arange(n) < lengths[i]
+      kd, vd = (np.where(mask[:, None], np.concatenate(
+          [pool[tables[i, j], layer, hh] for j in range(nb)]), 0.0)
+                for pool in (k_pool, v_pool))
       want = pqa.exact_decode_attention(
-          q[i, hh], jnp.asarray(kd), jnp.asarray(vd), jnp.asarray(mask),
-          scale)
-      np.testing.assert_allclose(np.asarray(out[i, hh]), np.asarray(want),
+          q[i, hh], jnp.asarray(kd, jnp.float32),
+          jnp.asarray(vd, jnp.float32), jnp.asarray(mask), scale)
+      np.testing.assert_allclose(out[i, hh], np.asarray(want),
                                  rtol=1e-4, atol=1e-4, err_msg=f"bh {i},{hh}")
+
+
+@settings(max_examples=8, deadline=None)
+@given(
+    g=st.integers(1, 4),
+    d=st.sampled_from([8, 16, 32, 128]),
+    blk=st.sampled_from([8, 16]),
+    nb=st.integers(2, 40),
+    seed=st.integers(0, 2**31 - 1),
+)
+def test_paged_flash_decode_matches_exact_oracle(g, d, blk, nb, seed):
+  """Random widths, table sizes (ragged page groups past 16 or 32 pages)
+  and lengths; d=128 fetches pages by hand, narrower heads pipelined."""
+  lengths = np.random.default_rng(seed).integers(1, blk * nb + 1, size=2)
+  _check_paged_flash_decode(g, d, blk, nb, 2, lengths, seed)
+
+
+# (g, d, blk, nb, kv heads, lengths); a group is 16 pages at blk=16
+@pytest.mark.parametrize("g,d,blk,nb,h,lengths", [
+    pytest.param(3, 128, 16, 21, 2, [300, 17, 336], id="nb-not-multiple"),
+    pytest.param(3, 128, 16, 21, 2, [1, 1], id="length-1"),
+    pytest.param(3, 128, 16, 21, 2, [16, 48], id="length-blk"),
+    pytest.param(3, 128, 16, 21, 2, [256, 257], id="length-one-group"),
+    pytest.param(3, 128, 16, 21, 2, [336, 336], id="length-full-table"),
+    pytest.param(7, 128, 16, 18, 8, [1, 256, 288, 100], id="yi34b-heads"),
+    pytest.param(8, 64, 16, 20, 4, [1, 16, 256, 320], id="pipelined-d64"),
+])
+def test_paged_flash_decode_matches_exact_oracle_at(g, d, blk, nb, h,
+                                                     lengths):
+  _check_paged_flash_decode(g, d, blk, nb, h, lengths, seed=len(lengths))
 
 
 def test_dense_flash_decode_matches_exact_oracle():

@@ -5,7 +5,9 @@ compiler for a described (not attached) v5e, at tinyllama-1.1b's full
 widths — g=8 query heads per kv head, head dim 64, PQ m=32 / K=512
 (dsub=2), 16-token pool blocks over 22 layers.  Interpret mode cannot show
 what these do: Mosaic refuses shape casts, gathers and loads it does not
-lower, and fast-memory budgets it cannot meet.
+lower, DMA slices narrower than a lane tile, and fast-memory budgets it
+cannot meet.  The exact paged kernel also compiles at yi-34b-exact's
+shapes (head dim 128, g=7), where it fetches pool pages by hand.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, so the worker that runs this file
@@ -71,13 +73,21 @@ def _flash(arg):
       scale=SCALE, blk=512, interpret=False)
 
 
-def _paged_flash(arg):
-  return pfd.paged_flash_decode_kernel.lower(
-      arg((BH, G, D), jnp.bfloat16),
-      arg((POOL, LAYERS, HEADS, BLOCK, D), jnp.bfloat16),
-      arg((POOL, LAYERS, HEADS, BLOCK, D), jnp.bfloat16),
-      arg((BH, NB), jnp.int32), arg((1,), jnp.int32), arg((BH,), jnp.int32),
-      scale=SCALE, interpret=False)
+def _paged_flash_at(rows, heads, g, d, pool, layers, nb):
+  def lower(arg):
+    return pfd.paged_flash_decode_kernel.lower(
+        arg((rows, heads, g, d), jnp.bfloat16),
+        arg((pool, layers, heads, BLOCK, d), jnp.bfloat16),
+        arg((pool, layers, heads, BLOCK, d), jnp.bfloat16),
+        arg((rows, nb), jnp.int32), arg((1,), jnp.int32),
+        arg((rows,), jnp.int32), scale=d ** -0.5, interpret=False)
+  return lower
+
+
+_paged_flash = _paged_flash_at(BH // HEADS, HEADS, G, D, POOL, LAYERS, NB)
+# yi-34b-exact's cell: 16 rows of a 4096-token table (256 pages), 8 kv
+# heads of 128 at g=7, 4 layers, 4096 pages and the trash page
+_paged_flash_yi34b = _paged_flash_at(16, 8, 7, 128, 4097, 4, 256)
 
 
 def _packed(bits):
@@ -96,6 +106,7 @@ def _packed(bits):
     pytest.param(_pq_paged, id="pq_decode_attention_paged_kernel"),
     pytest.param(_flash, id="flash_decode_kernel"),
     pytest.param(_paged_flash, id="paged_flash_decode_kernel"),
+    pytest.param(_paged_flash_yi34b, id="paged_flash_decode_kernel-yi34b"),
     pytest.param(_packed(4), id="packed_paged_flash_decode_kernel-q4"),
     pytest.param(_packed(8), id="packed_paged_flash_decode_kernel-q8"),
 ])
