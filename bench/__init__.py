@@ -4,7 +4,10 @@ Everything that belongs to one model configuration, one traffic mix or one
 per-layer metric is a file of its own, found by the name `BENCHMARK.json`
 gives it:
 
-  configs/<config>.json       published widths, the cuts, the serve settings
+  configs/<config>.json       published widths, the cuts, the serve
+                              settings, and the model family it names
+  families/<family>.py        a model family's weights, reference block and
+                              per-layer weight FLOPs
   workloads/<cell>.json       engine sizes, traffic kind and its parameters,
                               correctness sample and limit
   traffic/<kind>.py           a generator for one kind of traffic

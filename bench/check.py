@@ -41,12 +41,13 @@ def readings(gaps: List[np.ndarray]) -> dict:
   return dict(max_logit_gap=float(g.max()), mean_logit_gap=float(g.mean()))
 
 
-def gaps(weights, hf: dict, picked: list, s_pad: int,
+def gaps(weights, hf: dict, family, picked: list, s_pad: int,
          control: bool = False, pq=None) -> List[np.ndarray]:
   """Per sampled request, the gap of each served token (or, with
   `control`, of each token the float8 reference would have served);
-  `pq` is `reference.pq_spec` of a PQ configuration."""
+  `family` is the configuration's `families/<family>.py` module, `pq`
+  `reference.pq_spec` of a PQ configuration."""
   return [reference.served_gaps(
-      weights, hf, np.asarray(s.handle.prompt, np.int32),
+      weights, hf, family, np.asarray(s.handle.prompt, np.int32),
       np.asarray(s.handle.tokens, np.int32), s_pad, control=control, pq=pq)
           for s in picked]

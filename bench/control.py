@@ -33,10 +33,11 @@ def read(cell: dict, seed: int, seconds: float) -> dict:
   picked = check.sample(list(o.run.rec.served.values()),
                         cell["workload"]["check"]["sample"], seed)
   t = time.perf_counter()
-  prog = check.gaps(o.weights, o.hf, picked, o.context_len, pq=o.pq)
-  t_ref = time.perf_counter() - t
-  ctrl = check.gaps(o.weights, o.hf, picked, o.context_len, control=True,
+  prog = check.gaps(o.weights, o.hf, o.family, picked, o.context_len,
                     pq=o.pq)
+  t_ref = time.perf_counter() - t
+  ctrl = check.gaps(o.weights, o.hf, o.family, picked, o.context_len,
+                    control=True, pq=o.pq)
   return dict(seed=seed, program=check.readings(prog),
               control=check.readings(ctrl),
               program_each=[float(g.max()) for g in prog],

@@ -1,11 +1,12 @@
 """Operations of one batched decode step of the whole model, counted over
 the live rows only (idle lanes do no useful work).
 
-Per live row: 2 x (weights it multiplies: per layer the q, k, v and output
-projections and the three SwiGLU matrices, plus the LM head), and per
-layer the attention the cache policy needs at the row's real length: the
-paged kernel's count (`pq_decode_attention_paged` or `paged_flash_decode`)
-and, for PQ, the exact attention over the sink and recent tokens.
+Per live row: 2 x (weights it multiplies: per layer the family's count,
+`layer_weight_flops` in the shape (`run.shape`), plus the LM head), and
+per layer the attention the cache policy needs at the row's real length:
+the paged kernel's count (`pq_decode_attention_paged` or
+`paged_flash_decode`) and, for PQ, the exact attention over the sink and
+recent tokens.
 """
 from __future__ import annotations
 
@@ -15,10 +16,10 @@ from bench.costs import paged_flash_decode, pq_decode_attention_paged
 
 
 def flops(lengths, shape: dict) -> float:
-  d, h, hkv, hd, f = (shape[k] for k in ("d", "h", "hkv", "hd", "f"))
-  per_layer = d * hd * (h + 2 * hkv) + h * hd * d + 3 * d * f
+  d, h, hd = shape["d"], shape["h"], shape["hd"]
   rows = len(lengths)
-  total = rows * 2.0 * (shape["layers"] * per_layer + d * shape["v"])
+  total = rows * 2.0 * (shape["layers"] * shape["layer_weight_flops"]
+                        + d * shape["v"])
   if shape["pq"] is None:
     attn, _ = paged_flash_decode.cost(lengths, shape)
   else:

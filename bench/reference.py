@@ -1,12 +1,15 @@
-"""Plain float32 reference of the dense decoder, computed in blocks.
+"""Plain float32 reference of a decoder, computed in blocks.
 
-RMSNorm, rotate-half RoPE, grouped-query causal attention, SwiGLU MLP and
-an untied LM head, as the Mistral and Yi model cards describe them.  It
-imports nothing of the program: it reads the benchmark's own weights
-(`weights.py`) and the tokens the program served.  Matrix products run at
-`Precision.HIGHEST`.  One layer is upcast to float32 at a time and the
-attention runs over blocks of query rows, so the reference fits beside the
-weights on one chip.
+What every family shares lives here: the embedding, RMSNorm, rotate-half
+RoPE, grouped-query causal attention and an untied LM head, as the Mistral
+and Yi model cards describe them.  The rest of a layer (for the dense
+family the SwiGLU MLP) is the family's `block`, from
+`families/<family>.py`; a family may bring its whole layer body as
+`layer`.  It imports nothing of the program: it reads the benchmark's own
+weights (the family's `make`) and the tokens the program served.  Matrix
+products run at `Precision.HIGHEST`.  One layer is upcast to float32 at a
+time and the attention runs over blocks of query rows, so the reference
+fits beside the weights on one chip.
 
 For a PQ configuration (`pq_spec`) the reference follows AQPIM's cache
 semantics as well, from the paper (sec. III) and the configuration alone:
@@ -34,8 +37,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench import weights as wlib
-
 HIGHEST = jax.lax.Precision.HIGHEST
 E4M3_MAX = 448.0
 Q_BLOCK = 512
@@ -49,14 +50,14 @@ def _fp8(x):
   return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
 
 
-def _mm(spec, a, b, low):
+def mm(spec, a, b, low):
   if low:
     a, b = _fp8(a), _fp8(b)
   return jnp.einsum(spec, a, b, precision=HIGHEST,
                     preferred_element_type=jnp.float32)
 
 
-def _rms(x, w, eps):
+def rms(x, w, eps):
   return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
 
 
@@ -102,14 +103,14 @@ def _pq_recon(xh, w, n_prompt, pq, low):
 
   def nearest(x, cb):                                         # (m, N, dsub)
     d2 = (jnp.sum(x * x, -1)[..., None]
-          - 2 * _mm("mnd,mkd->mnk", x, cb, low)
+          - 2 * mm("mnd,mkd->mnk", x, cb, low)
           + jnp.sum(cb * cb, -1)[:, None, :])
     return jnp.argmin(d2, axis=-1)
 
   xb = sub(body)
   for _ in range(iters):
     one = jax.nn.one_hot(nearest(xb, cb), k_cent) * wb[None, :, None]
-    num = _mm("mnk,mnd->mkd", one, xb, low)
+    num = mm("mnk,mnd->mkd", one, xb, low)
     den = jnp.sum(one, axis=1)[..., None]
     cb = jnp.where(den > 1e-12, num / jnp.maximum(den, 1e-12), cb)
   # codebooks are stored in bfloat16: the prompt's body is coded with the
@@ -134,17 +135,17 @@ def _rope(x, theta):
   return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "theta", "low", "pq"))
-def _layer(x, layers, i, n_prompt, eps, theta, low, pq):
-  lw = jax.tree.map(lambda a: a[i].astype(jnp.float32), layers)
+def attention(x, lw, n_prompt, eps, theta, low, pq):
+  """A layer's attention with its norm and residual, traced inside a layer
+  body: x (S, D), lw the layer's weights upcast to float32."""
   s = x.shape[0]
   at = lw["attn"]
   hkv, hd = at["wk"].shape[1], at["wk"].shape[2]
   g = at["wq"].shape[1] // hkv
-  h = _rms(x, lw["ln1"]["scale"], eps)
-  q = _rope(_mm("sd,dhk->shk", h, at["wq"], low), theta)
-  k = _rope(_mm("sd,dhk->shk", h, at["wk"], low), theta)
-  v = _mm("sd,dhk->shk", h, at["wv"], low)
+  h = rms(x, lw["ln1"]["scale"], eps)
+  q = _rope(mm("sd,dhk->shk", h, at["wq"], low), theta)
+  k = _rope(mm("sd,dhk->shk", h, at["wk"], low), theta)
+  v = mm("sd,dhk->shk", h, at["wv"], low)
   qg = q.reshape(s, hkv, g, hd)
   kpos = jnp.arange(s)
   if pq is not None:
@@ -158,37 +159,40 @@ def _layer(x, layers, i, n_prompt, eps, theta, low, pq):
                                       jnp.moveaxis(v, 1, 0)))
     k_hat, v_hat = jnp.moveaxis(k_hat, 0, 1), jnp.moveaxis(v_hat, 0, 1)
 
-  def block(start):
+  def q_block(start):
     qb = jax.lax.dynamic_slice_in_dim(qg, start, Q_BLOCK, 0)
-    sc = _mm("bhgk,thk->hgbt", qb, k, low) * hd ** -0.5
+    sc = mm("bhgk,thk->hgbt", qb, k, low) * hd ** -0.5
     qpos = start + jnp.arange(Q_BLOCK)
     if pq is not None:
       # decode rows read body tokens [sink, row - recent] through the codes
       coded = ((qpos[:, None] >= n_prompt) & (kpos[None, :] >= pq[0])
                & (kpos[None, :] <= qpos[:, None] - pq[1]))
-      sc = jnp.where(coded, _mm("bhgk,thk->hgbt", qb, k_hat, low)
+      sc = jnp.where(coded, mm("bhgk,thk->hgbt", qb, k_hat, low)
                      * hd ** -0.5, sc)
     sc = jnp.where(kpos[None, :] <= qpos[:, None], sc, -jnp.inf)
     p = jax.nn.softmax(sc, axis=-1)
     if pq is None:
-      return _mm("hgbt,thk->bhgk", p, v, low).reshape(Q_BLOCK, hkv * g, hd)
-    o = (_mm("hgbt,thk->bhgk", jnp.where(coded, 0.0, p), v, low)
-         + _mm("hgbt,thk->bhgk", jnp.where(coded, p, 0.0), v_hat, low))
+      return mm("hgbt,thk->bhgk", p, v, low).reshape(Q_BLOCK, hkv * g, hd)
+    o = (mm("hgbt,thk->bhgk", jnp.where(coded, 0.0, p), v, low)
+         + mm("hgbt,thk->bhgk", jnp.where(coded, p, 0.0), v_hat, low))
     return o.reshape(Q_BLOCK, hkv * g, hd)
 
-  o = jax.lax.map(block, jnp.arange(0, s, Q_BLOCK)).reshape(s, hkv * g, hd)
-  x = x + _mm("shk,hkd->sd", o, at["wo"], low)
-  h = _rms(x, lw["ln2"]["scale"], eps)
-  mlp = lw["mlp"]
-  a = jax.nn.silu(_mm("sd,df->sf", h, mlp["w_gate"], low))
-  a = a * _mm("sd,df->sf", h, mlp["w_up"], low)
-  return x + _mm("sf,fd->sd", a, mlp["w_down"], low)
+  o = jax.lax.map(q_block, jnp.arange(0, s, Q_BLOCK)).reshape(s, hkv * g, hd)
+  return x + mm("shk,hkd->sd", o, at["wo"], low)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("eps", "theta", "low", "pq", "block"))
+def _layer(x, layers, i, n_prompt, eps, theta, low, pq, block):
+  """Layer `i`: the shared attention, then the family's `block`."""
+  lw = jax.tree.map(lambda a: a[i].astype(jnp.float32), layers)
+  return block(attention(x, lw, n_prompt, eps, theta, low, pq), lw, eps, low)
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "low"))
 def _logits(x, norm, head, eps, low):
-  h = _rms(x, norm.astype(jnp.float32), eps)
-  return _mm("sd,dv->sv", h, head.astype(jnp.float32), low)
+  h = rms(x, norm.astype(jnp.float32), eps)
+  return mm("sd,dv->sv", h, head.astype(jnp.float32), low)
 
 
 @jax.jit
@@ -198,23 +202,27 @@ def _gap(logits, picked):
   return jnp.max(logits, axis=-1) - got
 
 
-def hidden(w: dict, hf: dict, tokens: np.ndarray, s_pad: int, low: bool,
-           pq=None, n_prompt: int = 0) -> jax.Array:
-  """Final hidden states (s_pad, D) of `tokens`, right-padded to s_pad;
-  with `pq`, rows from `n_prompt` on read the body through codebooks."""
+def hidden(w: dict, hf: dict, family, tokens: np.ndarray, s_pad: int,
+           low: bool, pq=None, n_prompt: int = 0) -> jax.Array:
+  """Final hidden states (s_pad, D) of `tokens`, right-padded to s_pad,
+  through the layers of `family` (a `families/<family>.py` module); with
+  `pq`, rows from `n_prompt` on read the body through codebooks."""
   s_pad = -(-s_pad // Q_BLOCK) * Q_BLOCK
   ids = np.zeros((s_pad,), np.int32)
   ids[:len(tokens)] = tokens
   x = jnp.take(w["embed"], jnp.asarray(ids), axis=0).astype(jnp.float32)
   eps, theta = float(hf["rms_norm_eps"]), float(hf["rope_theta"])
-  for i in range(wlib.dims(hf)["layers"]):
-    x = _layer(x, w["layers"], jnp.int32(i), jnp.int32(n_prompt), eps,
-               theta, low, pq)
+  layer = getattr(family, "layer", None) or functools.partial(
+      _layer, block=family.block)
+  for i in range(family.dims(hf)["layers"]):
+    x = layer(x, w["layers"], jnp.int32(i), jnp.int32(n_prompt), eps,
+              theta, low, pq)
   return x
 
 
-def served_gaps(w: dict, hf: dict, prompt: np.ndarray, served: np.ndarray,
-                s_pad: int, control: bool = False, pq=None) -> np.ndarray:
+def served_gaps(w: dict, hf: dict, family, prompt: np.ndarray,
+                served: np.ndarray, s_pad: int, control: bool = False,
+                pq=None) -> np.ndarray:
   """For each served token, the gap by which the reference's logit for it
   lies below the reference's best at that position.
 
@@ -228,10 +236,10 @@ def served_gaps(w: dict, hf: dict, prompt: np.ndarray, served: np.ndarray,
   rows = np.full((-(-n // ROW_BUCKET) * ROW_BUCKET,), len(seq) - 1)
   rows[:n] = np.arange(len(prompt) - 1, len(seq))
   eps = float(hf["rms_norm_eps"])
-  x = hidden(w, hf, seq, s_pad, False, pq, len(prompt))
+  x = hidden(w, hf, family, seq, s_pad, False, pq, len(prompt))
   ref = _logits(x[rows], w["final_norm"]["scale"], w["lm_head"], eps, False)
   if control:
-    xl = hidden(w, hf, seq, s_pad, True, pq, len(prompt))
+    xl = hidden(w, hf, family, seq, s_pad, True, pq, len(prompt))
     low = _logits(xl[rows], w["final_norm"]["scale"], w["lm_head"], eps,
                   True)
     picked = jnp.argmax(low, axis=-1).astype(jnp.int32)

@@ -28,6 +28,7 @@ import pathlib  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
+from types import ModuleType  # noqa: E402
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
@@ -35,11 +36,10 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 import jax  # noqa: E402
 
 from bench import check, drive, peaks, reference, spec, trace_reduce  # noqa: E402
-from bench import weights as wlib  # noqa: E402
 
-#: HF config key -> program ModelConfig field
-HF_KEYS = dict(hidden_size="d_model", intermediate_size="d_ff",
-               num_attention_heads="n_heads",
+#: HF config key -> program ModelConfig field, for every family; a family
+#: adds its own (`HF_KEYS` of `families/<family>.py`)
+HF_KEYS = dict(hidden_size="d_model", num_attention_heads="n_heads",
                num_key_value_heads="n_kv_heads", head_dim="head_dim",
                num_hidden_layers="n_layers", vocab_size="vocab_size",
                rope_theta="rope_theta", rms_norm_eps="norm_eps")
@@ -57,18 +57,20 @@ def _process_age() -> float:
     return time.perf_counter() - T_PROCESS
 
 
-def model_config(conf: dict):
+def model_config(conf: dict, family):
   from repro.configs import get_arch
   hf = conf["hf_config"]
-  over = {v: hf[k] for k, v in HF_KEYS.items() if hf.get(k) is not None}
+  keys = {**HF_KEYS, **family.HF_KEYS}
+  over = {v: hf[k] for k, v in keys.items() if hf.get(k) is not None}
   over["dtype_str"] = hf.get("torch_dtype", "bfloat16")
   over.update(conf["serving"])
   return dataclasses.replace(get_arch(conf["arch"]), **over)
 
 
-def shape(conf: dict, context_len: int) -> dict:
+def shape(conf: dict, family, context_len: int) -> dict:
   """Sizes the cost functions take, from the configuration file."""
-  out = dict(wlib.dims(conf["hf_config"]), pq=None)
+  out = dict(family.dims(conf["hf_config"]), pq=None)
+  out["layer_weight_flops"] = family.layer_weight_flops(out)
   pq = reference.pq_spec(conf["serving"], context_len)
   if pq is not None:
     sink, recent, m, k, _, nb = pq
@@ -131,6 +133,7 @@ class Outcome:
   weights: dict
   hf: dict
   context_len: int
+  family: ModuleType              # families/<family>.py of the configuration
   pq: tuple | None                # reference.pq_spec of the configuration
 
 
@@ -145,8 +148,9 @@ def serve(cell: dict, seed: int, seconds: float, trace: bool,
 
   conf, wl = cell["config"], cell["workload"]
   hf, sizes = conf["hf_config"], wl["engine"]
-  cfg = model_config(conf)
-  weights = wlib.make(hf, seed, cfg.dtype)
+  family = spec.family(conf["family"])
+  cfg = model_config(conf, family)
+  weights = family.make(hf, seed, cfg.dtype)
   _check_tree(weights, Model(cfg, context_len=sizes["context_len"]))
   engine = ServeEngine(cfg, params=weights, seed=seed, **sizes)
   traffic = spec.traffic_kind(wl["traffic"]).generate(
@@ -182,10 +186,10 @@ def serve(cell: dict, seed: int, seconds: float, trace: bool,
     device.update(busy_s=reduced["busy_s"], window_s=reduced["window_s"])
   # device metrics need the chip's peaks; off the chip they stay unread
   chip = peaks.peaks(device["kind"]) if device["platform"] == "tpu" else None
-  run = Run(window, rec, setup_s, reduced, shape(conf, sizes["context_len"]),
-            chip)
+  run = Run(window, rec, setup_s, reduced,
+            shape(conf, family, sizes["context_len"]), chip)
   return Outcome(run, device, failed, window.attempted, weights, hf,
-                 sizes["context_len"],
+                 sizes["context_len"], family,
                  reference.pq_spec(conf["serving"], sizes["context_len"]))
 
 
@@ -203,7 +207,8 @@ def run_cell(name: str, cell: dict, metric_specs: list, seed: int,
 
   lim = cell["workload"]["check"]
   picked = check.sample(list(o.run.rec.served.values()), lim["sample"], seed)
-  gaps = check.gaps(o.weights, o.hf, picked, o.context_len, pq=o.pq)
+  gaps = check.gaps(o.weights, o.hf, o.family, picked, o.context_len,
+                    pq=o.pq)
   compared = int(sum(len(g) for g in gaps))
   read = check.readings(gaps)
   limits = lim["limits"]

@@ -1,5 +1,5 @@
-"""Finds a cell's configuration, workload, traffic kind and metric readers
-by the names `BENCHMARK.json` gives them."""
+"""Finds a cell's configuration, workload, model family, traffic kind and
+metric readers by the names `BENCHMARK.json` and the files give them."""
 from __future__ import annotations
 
 import importlib.util
@@ -10,6 +10,8 @@ from typing import List
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 HERE = pathlib.Path(__file__).resolve().parent
+#: where `family` finds `<family>.py`; a test may point it elsewhere
+FAMILIES = HERE / "families"
 
 
 def manifest() -> dict:
@@ -54,6 +56,12 @@ def _module(path: pathlib.Path) -> ModuleType:
   mod = importlib.util.module_from_spec(spec)
   spec.loader.exec_module(mod)
   return mod
+
+
+def family(name: str) -> ModuleType:
+  """The model family a configuration file names (`"family"`): weights,
+  reference block and step FLOPs (`families/dense.py` lists them)."""
+  return _module(FAMILIES / f"{name}.py")
 
 
 def traffic_kind(kind: str) -> ModuleType:

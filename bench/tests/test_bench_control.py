@@ -21,7 +21,6 @@ ROOT = pathlib.Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 from bench import check, drive, reference, run, spec  # noqa: E402
-from bench import weights as wlib  # noqa: E402
 
 HF = dict(hidden_size=64, intermediate_size=128, num_attention_heads=4,
           num_key_value_heads=2, head_dim=16, num_hidden_layers=2,
@@ -32,7 +31,8 @@ EXACT = dict(cache_policy="exact", cache_layout="paged", scheduler="paged",
 LIMIT = 1e-3     # float32 program against float32 reference reads 0
 PQ = dict(EXACT, cache_policy="pq", pq_m=4, pq_k=16, pq_sink=4, pq_recent=8)
 PQ_LIMIT = 0.5   # bf16 codebook storage at K=16: see test_bench_reference
-CELL = dict(config=dict(arch="yi-34b", hf_config=HF, serving=EXACT),
+CELL = dict(config=dict(arch="yi-34b", family="dense", hf_config=HF,
+                        serving=EXACT),
             workload=dict(
                 traffic="closed_sessions",
                 engine=dict(context_len=128, prompt_capacity=96, max_batch=3),
@@ -44,8 +44,9 @@ CELL = dict(config=dict(arch="yi-34b", hf_config=HF, serving=EXACT),
 @pytest.mark.parametrize("seed", [1, 2, 3])
 def test_control_reads_above_the_limit(seed, serving, limit):
   from repro.launch.engine import ServeEngine
-  cfg = run.model_config(dict(CELL["config"], serving=serving))
-  w = wlib.make(HF, seed, cfg.dtype)
+  dense = spec.family("dense")
+  cfg = run.model_config(dict(CELL["config"], serving=serving), dense)
+  w = dense.make(HF, seed, cfg.dtype)
   eng = ServeEngine(cfg, params=w, context_len=128, prompt_capacity=96,
                     max_batch=2)
   rng = np.random.default_rng(seed)
@@ -54,8 +55,8 @@ def test_control_reads_above_the_limit(seed, serving, limit):
     eng.step()
   served = [drive.Served(h) for h in hs]
   pq = reference.pq_spec(serving, 128)
-  prog = check.gaps(w, HF, served, 128, pq=pq)
-  ctrl = check.gaps(w, HF, served, 128, control=True, pq=pq)
+  prog = check.gaps(w, HF, dense, served, 128, pq=pq)
+  ctrl = check.gaps(w, HF, dense, served, 128, control=True, pq=pq)
   assert max(float(g.max()) for g in prog) <= limit
   assert max(float(g.max()) for g in ctrl) > 3 * limit
 

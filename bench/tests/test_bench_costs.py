@@ -1,5 +1,6 @@
 """Kernel operation and byte counts against hand arithmetic at a small
-shape, and the peak table's refusal of an unknown chip."""
+shape (the step's weight count from the dense family), and the peak
+table's refusal of an unknown chip."""
 import pathlib
 import sys
 
@@ -8,11 +9,13 @@ import pytest
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-from bench import peaks  # noqa: E402
+from bench import peaks, spec  # noqa: E402
 from bench.costs import decode_step, paged_flash_decode  # noqa: E402
 from bench.costs import pq_decode_attention_paged as pqk  # noqa: E402
 
 SHAPE = dict(layers=2, d=16, h=4, hkv=2, hd=8, f=32, v=50, pq=None)
+# the dense family's count, as `run.shape` adds it
+SHAPE["layer_weight_flops"] = spec.family("dense").layer_weight_flops(SHAPE)
 PQ = dict(SHAPE, pq=dict(m=2, k=4, sink=2, recent=3, body_capacity=16,
                          index_bytes=2))
 

@@ -48,7 +48,8 @@ LAYER = _specs(["host_gap_ms", "decode_step_ms",
 
 
 def _line(serving, workload, trace, seed=2**31 + 7):
-  cell = dict(config=dict(arch="mistral-7b", hf_config=HF, serving=serving),
+  cell = dict(config=dict(arch="mistral-7b", family="dense", hf_config=HF,
+                          serving=serving),
               workload=workload)
   out = run.run_cell("cell", cell, LAYER if trace else E2E, seed, 1.0, trace,
                      time.perf_counter())

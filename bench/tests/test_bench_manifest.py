@@ -73,3 +73,10 @@ def test_bounds():
   for m in MAN["end_to_end"]:
     assert 0.01 <= m["bound"] <= 0.25, m
   assert {m["name"]: m["bound"] for m in MAN["end_to_end"]}["setup_s"] <= 0.25
+
+
+def test_every_configuration_names_a_family_with_a_file():
+  for c in MAN["configs"]:
+    family = json.loads((ROOT / c["file"]).read_text())["family"]
+    assert NAME.match(family), c["name"]
+    assert (ROOT / "bench" / "families" / f"{family}.py").is_file(), family

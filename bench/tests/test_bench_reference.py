@@ -16,7 +16,7 @@ import pytest
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-from bench import check, drive, reference, run, weights as wlib  # noqa: E402
+from bench import check, drive, reference, run, spec  # noqa: E402
 
 HF = dict(hidden_size=64, intermediate_size=128, num_attention_heads=4,
           num_key_value_heads=2, head_dim=16, num_hidden_layers=2,
@@ -37,8 +37,10 @@ PQ = dict(PAGED, cache_policy="pq", pq_m=4, pq_k=16, pq_sink=4, pq_recent=8)
 ])
 def test_engine_tokens_are_reference_greedy(serving, seed, tol, share):
   from repro.launch.engine import ServeEngine
-  cfg = run.model_config(dict(arch="yi-34b", hf_config=HF, serving=serving))
-  w = wlib.make(HF, seed, cfg.dtype)
+  dense = spec.family("dense")
+  cfg = run.model_config(dict(arch="yi-34b", hf_config=HF, serving=serving),
+                         dense)
+  w = dense.make(HF, seed, cfg.dtype)
   eng = ServeEngine(cfg, params=w, context_len=128, prompt_capacity=96,
                     max_batch=2)
   rng = np.random.default_rng(0)
@@ -46,7 +48,7 @@ def test_engine_tokens_are_reference_greedy(serving, seed, tol, share):
   hs = [eng.submit(rng.integers(1, 256, size=p), new) for p in prompts]
   while eng.has_work:
     eng.step()
-  gaps = check.gaps(w, HF, [drive.Served(h) for h in hs], 128,
+  gaps = check.gaps(w, HF, dense, [drive.Served(h) for h in hs], 128,
                     pq=reference.pq_spec(serving, 128))
   assert [len(g) for g in gaps] == [new] * len(prompts)
   g = np.concatenate(gaps)
@@ -55,5 +57,5 @@ def test_engine_tokens_are_reference_greedy(serving, seed, tol, share):
 
 
 def test_reference_follows_weight_dims():
-  d = wlib.dims(dict(HF, head_dim=None))
+  d = spec.family("dense").dims(dict(HF, head_dim=None))
   assert (d["hd"], d["layers"], d["v"]) == (16, 2, 256)

@@ -97,7 +97,8 @@ def test_readers_on_a_served_cpu_window():
                               max_batch=4),
                   params=dict(sessions=4, prompt_len=[40, 48], refills=8),
                   check=dict(sample=1, limits=dict(max_logit_gap=1e-3)))
-  cell = dict(config=dict(arch="mistral-7b", hf_config=hf, serving=serving),
+  cell = dict(config=dict(arch="mistral-7b", family="dense", hf_config=hf,
+                          serving=serving),
               workload=workload)
   timing.clear()
   o = run.serve(cell, 2**31 + 11, 1.0, False, time.perf_counter())
