@@ -28,8 +28,12 @@ def body_tokens(lengths, shape: dict) -> np.ndarray:
   return np.clip(n - pq["sink"] - pq["recent"], 0, pq["body_capacity"])
 
 
-def cost(lengths, shape: dict):
-  """(flops, bytes) of one call over the live rows' `lengths`."""
+def cost(lengths, shape: dict, window=None):
+  """(flops, bytes) of one call over the live rows' `lengths`; a layer
+  with a window is not counted (no PQ configuration has one yet)."""
+  if window is not None:
+    raise ValueError("the PQ kernel's count has no sliding window; a PQ "
+                     "configuration with windowed layers is not supported")
   pq = shape["pq"]
   g, d, hkv = shape["h"] // shape["hkv"], shape["hd"], shape["hkv"]
   m, k = pq["m"], pq["k"]

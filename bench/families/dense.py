@@ -6,19 +6,27 @@ found by the `"family"` a configuration file names (`spec.family`):
 
   HF_KEYS             its HF config keys -> program `ModelConfig` fields,
                       over the common ones in `run.py`
-  dims(hf)            the sizes `make`, the reference and the costs take
+  dims(hf)            the one place the family reads its configuration:
+                      the sizes `make`, the reference and the costs take,
+                      and its settings, as hashable values (ints, floats,
+                      None, tuples) that reach `block` and `layer`; every
+                      family gives `windows` and `rope`, one entry a layer
+                      (`reference.settings_of` says what they mean)
   make(hf, seed, dtype)  the program's parameter tree, drawn from the seed
                       on the device in one jitted call
-  block(x, lw, eps, low)  the reference's layer after the shared attention
-                      (`reference.attention`), with its norm and residual;
-                      lw is the layer's weights upcast to float32
+  block(x, lw, eps, low, settings)  the reference's layer after the shared
+                      attention (`reference.attention`, which applies the
+                      layer's window and RoPE), with its norm and residual;
+                      lw is the layer's weights upcast to float32,
+                      `settings` the family's `dims`
   layer_weight_flops(shape)  the weights one live row multiplies in one
                       layer (`costs/decode_step.py` doubles it and adds the
-                      LM head and the attention)
+                      LM head and the attention, counted at each layer's
+                      window)
 
 and, where the shared attention does not fit the family, `layer(x,
-layers, i, n_prompt, eps, theta, low, pq)`, a jitted layer body that the
-reference runs in place of attention and `block`.
+layers, i, n_prompt, eps, low, pq, settings)`, a jitted layer body that
+the reference runs in place of attention and `block`.
 """
 from __future__ import annotations
 
@@ -34,12 +42,16 @@ HF_KEYS = dict(intermediate_size="d_ff")
 
 
 def dims(hf: dict) -> dict:
-  """Sizes the weights and the reference need, from an HF-style config."""
+  """Sizes the weights and the reference need, from an HF-style config;
+  every layer attends over the whole length with HF's default RoPE at
+  `rope_theta`, amplitude 1."""
   d = hf["hidden_size"]
   h = hf["num_attention_heads"]
+  n = hf["num_hidden_layers"]
   return dict(d=d, h=h, hkv=hf["num_key_value_heads"],
               hd=hf.get("head_dim") or d // h, f=hf["intermediate_size"],
-              v=hf["vocab_size"], layers=hf["num_hidden_layers"])
+              v=hf["vocab_size"], layers=n, windows=(None,) * n,
+              rope=((float(hf["rope_theta"]), 1.0),) * n)
 
 
 @functools.partial(jax.jit, static_argnames=("shape", "dtype"))
@@ -77,8 +89,9 @@ def make(hf: dict, seed: int, dtype=jnp.bfloat16) -> dict:
   return _make(wlib.key(seed), shape, jnp.dtype(dtype))
 
 
-def block(x, lw, eps, low):
+def block(x, lw, eps, low, settings):
   """The SwiGLU MLP after the second norm, with its residual."""
+  del settings
   h = ref.rms(x, lw["ln2"]["scale"], eps)
   mlp = lw["mlp"]
   a = jax.nn.silu(ref.mm("sd,df->sf", h, mlp["w_gate"], low))

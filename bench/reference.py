@@ -5,11 +5,13 @@ RoPE, grouped-query causal attention and an untied LM head, as the Mistral
 and Yi model cards describe them.  The rest of a layer (for the dense
 family the SwiGLU MLP) is the family's `block`, from
 `families/<family>.py`; a family may bring its whole layer body as
-`layer`.  It imports nothing of the program: it reads the benchmark's own
-weights (the family's `make`) and the tokens the program served.  Matrix
-products run at `Precision.HIGHEST`.  One layer is upcast to float32 at a
-time and the attention runs over blocks of query rows, so the reference
-fits beside the weights on one chip.
+`layer`.  Both see the family's settings, its `dims` of the configuration
+(`settings_of`), and the shared attention applies each layer's window and
+RoPE from them.  It imports nothing of the program: it reads the
+benchmark's own weights (the family's `make`) and the tokens the program
+served.  Matrix products run at `Precision.HIGHEST`.  One layer is upcast
+to float32 at a time and the attention runs over blocks of query rows, so
+the reference fits beside the weights on one chip.
 
 For a PQ configuration (`pq_spec`) the reference follows AQPIM's cache
 semantics as well, from the paper (sec. III) and the configuration alone:
@@ -124,27 +126,88 @@ def _pq_recon(xh, w, n_prompt, pq, low):
   return rec.transpose(1, 0, 2).reshape(s, hd)
 
 
-def _rope(x, theta):
-  """x (S, H, hd): rotate-half RoPE at positions 0..S-1."""
+class Settings(dict):
+  """A family's `dims`: sizes and settings, hashable so that a jitted layer
+  takes them as one static argument."""
+
+  def __hash__(self):
+    return hash(tuple(sorted(self.items())))
+
+
+def settings_of(family, hf: dict) -> Settings:
+  """`family.dims(hf)`, checked: a window and a RoPE for every layer.
+
+  `windows[i]` is None for full causal attention, or W: query i sees keys
+  j with i - W < j <= i (HF's `sliding_window`).  `rope[i]` is (inverse
+  frequencies, amplitude): the frequencies as a tuple of head_dim / 2
+  floats, or a theta for HF's default 1/theta^(j/half); the amplitude
+  multiplies cos and sin, as YaRN's attention factor does."""
+  s = Settings(family.dims(hf))
+  n = s["layers"]
+  if len(s["windows"]) != n or len(s["rope"]) != n:
+    raise ValueError(f"{n} layers need {n} windows and {n} RoPE entries, "
+                     f"not {len(s['windows'])} and {len(s['rope'])}")
+  if any(w is not None and (not isinstance(w, int) or w < 1)
+         for w in s["windows"]):
+    raise ValueError(f"a window is None or a whole number of at least 1, "
+                     f"not {s['windows']}")
+  return s
+
+
+def _inv_freq(base, half):
+  if isinstance(base, tuple):
+    return jnp.asarray(base, jnp.float32)
+  return 1.0 / base ** (jnp.arange(half, dtype=jnp.float32) / half)
+
+
+def _rope(x, rope, i):
+  """x (S, H, hd): rotate-half RoPE at positions 0..S-1 with layer i's
+  entry of `rope` (`settings_of`)."""
   s, _, hd = x.shape
   half = hd // 2
-  inv = 1.0 / theta ** (jnp.arange(half, dtype=jnp.float32) / half)
+  amp = None
+  if len(set(rope)) == 1:
+    base, a = rope[0]
+    inv = _inv_freq(base, half)
+    if a != 1.0:
+      amp = a
+  else:
+    inv = jnp.stack([_inv_freq(b, half) for b, _ in rope])[i]
+    amp = jnp.asarray([a for _, a in rope], jnp.float32)[i]
   ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv[None, :]
   cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+  if amp is not None:
+    cos, sin = cos * amp, sin * amp
   x1, x2 = x[..., :half], x[..., half:]
   return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def attention(x, lw, n_prompt, eps, theta, low, pq):
-  """A layer's attention with its norm and residual, traced inside a layer
-  body: x (S, D), lw the layer's weights upcast to float32."""
+def _visible(kpos, qpos, windows, i):
+  """Keys each query row sees in layer i: causal, and inside the layer's
+  window where it has one."""
+  seen = kpos[None, :] <= qpos[:, None]
+  if all(w is None for w in windows):
+    return seen
+  w = jnp.asarray([kpos.shape[0] if w is None else w for w in windows],
+                  jnp.int32)[i]
+  return seen & (kpos[None, :] > qpos[:, None] - w)
+
+
+def attention(x, lw, i, n_prompt, eps, low, pq, settings):
+  """Layer i's attention with its norm and residual, traced inside a layer
+  body: x (S, D), lw the layer's weights upcast to float32, `settings` the
+  family's (`settings_of`)."""
   s = x.shape[0]
+  windows, rope = settings["windows"], settings["rope"]
+  if pq is not None and any(w is not None for w in windows):
+    raise ValueError("the PQ reference has no sliding window; a PQ "
+                     "configuration with windowed layers is not supported")
   at = lw["attn"]
   hkv, hd = at["wk"].shape[1], at["wk"].shape[2]
   g = at["wq"].shape[1] // hkv
   h = rms(x, lw["ln1"]["scale"], eps)
-  q = _rope(mm("sd,dhk->shk", h, at["wq"], low), theta)
-  k = _rope(mm("sd,dhk->shk", h, at["wk"], low), theta)
+  q = _rope(mm("sd,dhk->shk", h, at["wq"], low), rope, i)
+  k = _rope(mm("sd,dhk->shk", h, at["wk"], low), rope, i)
   v = mm("sd,dhk->shk", h, at["wv"], low)
   qg = q.reshape(s, hkv, g, hd)
   kpos = jnp.arange(s)
@@ -169,7 +232,7 @@ def attention(x, lw, n_prompt, eps, theta, low, pq):
                & (kpos[None, :] <= qpos[:, None] - pq[1]))
       sc = jnp.where(coded, mm("bhgk,thk->hgbt", qb, k_hat, low)
                      * hd ** -0.5, sc)
-    sc = jnp.where(kpos[None, :] <= qpos[:, None], sc, -jnp.inf)
+    sc = jnp.where(_visible(kpos, qpos, windows, i), sc, -jnp.inf)
     p = jax.nn.softmax(sc, axis=-1)
     if pq is None:
       return mm("hgbt,thk->bhgk", p, v, low).reshape(Q_BLOCK, hkv * g, hd)
@@ -182,11 +245,12 @@ def attention(x, lw, n_prompt, eps, theta, low, pq):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("eps", "theta", "low", "pq", "block"))
-def _layer(x, layers, i, n_prompt, eps, theta, low, pq, block):
+                   static_argnames=("eps", "low", "pq", "settings", "block"))
+def _layer(x, layers, i, n_prompt, eps, low, pq, settings, block):
   """Layer `i`: the shared attention, then the family's `block`."""
   lw = jax.tree.map(lambda a: a[i].astype(jnp.float32), layers)
-  return block(attention(x, lw, n_prompt, eps, theta, low, pq), lw, eps, low)
+  return block(attention(x, lw, i, n_prompt, eps, low, pq, settings), lw,
+               eps, low, settings)
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "low"))
@@ -211,12 +275,12 @@ def hidden(w: dict, hf: dict, family, tokens: np.ndarray, s_pad: int,
   ids = np.zeros((s_pad,), np.int32)
   ids[:len(tokens)] = tokens
   x = jnp.take(w["embed"], jnp.asarray(ids), axis=0).astype(jnp.float32)
-  eps, theta = float(hf["rms_norm_eps"]), float(hf["rope_theta"])
+  eps, settings = float(hf["rms_norm_eps"]), settings_of(family, hf)
   layer = getattr(family, "layer", None) or functools.partial(
       _layer, block=family.block)
-  for i in range(family.dims(hf)["layers"]):
-    x = layer(x, w["layers"], jnp.int32(i), jnp.int32(n_prompt), eps,
-              theta, low, pq)
+  for i in range(settings["layers"]):
+    x = layer(x, w["layers"], jnp.int32(i), jnp.int32(n_prompt), eps, low,
+              pq, settings)
   return x
 
 

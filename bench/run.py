@@ -68,8 +68,9 @@ def model_config(conf: dict, family):
 
 
 def shape(conf: dict, family, context_len: int) -> dict:
-  """Sizes the cost functions take, from the configuration file."""
-  out = dict(family.dims(conf["hf_config"]), pq=None)
+  """Sizes the cost functions take, from the configuration file: the
+  family's settings (`reference.settings_of`), its weight count and PQ's."""
+  out = dict(reference.settings_of(family, conf["hf_config"]), pq=None)
   out["layer_weight_flops"] = family.layer_weight_flops(out)
   pq = reference.pq_spec(conf["serving"], context_len)
   if pq is not None:
