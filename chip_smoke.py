@@ -265,8 +265,58 @@ def phase_parity(seed: int) -> None:
   got = ops.paged_flash_decode(q, k_pool, v_pool, tables,
                                jnp.asarray(0, jnp.int32), lengths, scale)
   _check("exact paged out", got, want, float(jnp.abs(v_pool).max()))
+  _exact_step_parity(seed)
   log(f"(e) kernel parity at full width: ok in "
       f"{time.perf_counter() - t0:.1f} s")
+
+
+def _exact_step_parity(seed: int) -> None:
+  """The exact paged step at head dim 128, where the kernel writes each new
+  row by whole-page write-back into pools aliased in place, against an XLA
+  row scatter before the read: the same output and the same pools, bit for
+  bit, on every block but the trash block."""
+  import jax
+  import jax.numpy as jnp
+
+  from repro.core import kv_cache as kvc
+  from repro.kernels import ops
+
+  h, g, d, blk, nb = 8, 7, 128, 16, 40
+  lengths = np.asarray([0, 15, 32, 37, 255, 256, nb * blk - 1], np.int32)
+  b = len(lengths)
+  trash = b * nb
+  rng = np.random.default_rng(seed)
+  tables = rng.permutation(trash).reshape(b, nb).astype(np.int32)
+  for i, n in enumerate(lengths):
+    tables[i, n // blk + 1 if n else 0:] = trash
+  ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+  bf16 = jnp.bfloat16
+  k_pool, v_pool = (jax.random.normal(k, (trash + 1, 2, h, blk, d), bf16)
+                    for k in ks[:2])
+  q = jax.random.normal(ks[2], (b, h * g, d), bf16)
+  k_new, v_new = (jax.random.normal(k, (b, h, d), bf16) for k in ks[3:])
+  tables, lengths, layer = (jnp.asarray(tables), jnp.asarray(lengths),
+                            jnp.asarray(1, jnp.int32))
+
+  @jax.jit
+  def scatter_then_read(k_pool, v_pool):
+    pids = tables[jnp.arange(b), lengths // blk]
+    k_pool = k_pool.at[pids, layer, :, lengths % blk].set(k_new)
+    v_pool = v_pool.at[pids, layer, :, lengths % blk].set(v_new)
+    out = ops.paged_flash_decode(q.reshape(b, h, g, d), k_pool, v_pool,
+                                 tables, layer, lengths + 1, d ** -0.5)
+    return out.reshape(b, h * g, d), k_pool, v_pool
+
+  want = scatter_then_read(k_pool, v_pool)
+  got = jax.jit(lambda kp, vp: kvc.exact_cache_paged_step(
+      kp, vp, layer, tables, q, k_new, v_new, lengths, d ** -0.5,
+      interpret=False))(k_pool, v_pool)
+  for label, x, y in zip(("out", "k_pool", "v_pool"), got, want):
+    x, y = np.asarray(x[:trash], np.float32), np.asarray(y[:trash],
+                                                         np.float32)
+    np.testing.assert_array_equal(x, y, err_msg=label)
+  log("    exact paged step at d=128 (kernel-written row): output and "
+      "pools bit-identical to a scatter")
 
 
 def phase_mesh(prompts, shards: int) -> None:

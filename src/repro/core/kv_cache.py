@@ -863,19 +863,21 @@ def exact_cache_paged_step(
     scale: float,
     interpret: bool,
 ):
-  """Block-table-native exact decode step: insert one row, attend in place."""
+  """Block-table-native exact decode step: insert one row, attend in place.
+
+  The kernel writes each row's new K/V row at position `length` itself:
+  for head dims that are whole lane tiles it replaces the row in the page
+  it fetched and sends the whole page back by DMA, the pools aliased in
+  place, so XLA never scatters into (or re-lays out) a pool; narrower head
+  dims are scattered in XLA before the call (`paged_flash_decode_kernel`).
+  """
   b, hq, d = q.shape
   h = k_pool.shape[2]
   g = hq // h
-  block = k_pool.shape[3]
   lengths = as_lengths(length, b)
-  pids = tables[jnp.arange(b), lengths // block]
-  rows = lengths % block
-  k_pool = k_pool.at[pids, layer, :, rows].set(k_new.astype(k_pool.dtype))
-  v_pool = v_pool.at[pids, layer, :, rows].set(v_new.astype(v_pool.dtype))
-  out = kops.paged_flash_decode(
+  out, k_pool, v_pool = kops.paged_flash_decode(
       q.reshape(b, h, g, d), k_pool, v_pool, tables, layer, lengths + 1,
-      scale, interpret=interpret)
+      scale, interpret=interpret, new=(k_new, v_new))
   return out.reshape(b, hq, d), k_pool, v_pool
 
 

@@ -146,12 +146,17 @@ def paged_flash_decode(
     length: jax.Array,   # (B,) valid tokens per row
     scale: float,
     interpret: Optional[bool] = None,
-) -> jax.Array:
-  """Block-table-native flash decode over pooled K/V (exact policy)."""
+    new: Optional[Tuple[jax.Array, jax.Array]] = None,
+):
+  """Block-table-native flash decode over pooled K/V (exact policy).
+
+  With `new` = (k_new, v_new) (B, H_kv, d), row `length - 1` is written
+  first and (out, k_pool, v_pool) returned (`paged_flash_decode_kernel`).
+  """
   return _pfd.paged_flash_decode_kernel(
       q, k_pool, v_pool, tables.astype(jnp.int32),
       jnp.reshape(layer, (1,)).astype(jnp.int32), length.astype(jnp.int32),
-      scale=scale, interpret=_auto_interpret(interpret))
+      scale=scale, interpret=_auto_interpret(interpret), new=new)
 
 
 def packed_paged_flash_decode(

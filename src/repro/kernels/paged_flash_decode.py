@@ -21,6 +21,15 @@ long-context decode bottleneck: the dense gather->decode->scatter round trip
 (2x the active KV through HBM per step) collapses to block reads plus the one
 inserted token row.
 
+The exact policy's step hands ``paged_flash_decode_kernel`` the new K/V row
+(``new``).  With hand-DMA head dims the kernel writes it: the row's last live
+page, fetched for attention anyway, has the row replaced in VMEM and goes
+back whole by one (H, blk, d) DMA into pools aliased input to output.  A
+bf16 pool packs row pairs into 32-bit words, so neither a one-row DMA nor an
+XLA row scatter fits its tiled layout: the scatter made XLA re-lay both
+pools out around every layer's kernel call.  Narrower head dims keep the
+XLA scatter before the call.
+
 ``paged_flash_decode_kernel`` grid: (B, ceil(nb / pps)), both sequential
 ("arbitrary").  A step covers ``pps`` consecutive table entries of one row
 for all H kv heads (q arrives (B, H, g, d)): scores (H, g, pps*blk) are one
@@ -32,7 +41,12 @@ table takes 16 steps a row, whatever the page size.  The last group may be
 ragged.  A group at or past a row's length fetches and computes nothing;
 the last live group fetches only its live pages, so pages past the length
 (and the trash block unallocated entries point at) are never read.  Rows
-past the length inside the last live page are masked, their values zeroed.
+past the length inside the last live page are masked, their values zeroed
+in the f32 copy only: a written-back page returns them as they were read.
+The write-back is waited on in the step that starts it, before its slot
+can be refilled.  Two rows never write one page (a partial page is never
+shared), except the trash block of rows of length 0, never read for a live
+row.
 
 What is fetched, by head dim:
 
@@ -57,7 +71,7 @@ weights, (acc, m, l) < 0.2 MiB.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -247,17 +261,21 @@ def _paged_flash_decode_kernel(
     layer_ref,             # (1,) int32
     length_ref,            # (B,) int32 — valid tokens per row
     q_ref,                 # (1, H, g, d)
-    k_hbm,                 # (P+1, L, H, blk, d) — the pool, left in HBM
-    v_hbm,
-    out_ref,               # (1, H, g, d) f32
-    k_buf,                 # (2, H, pps*blk, d) — two slots of fetched pages
-    v_buf,
-    sems,                  # DMA (2, 2): [K or V, slot]
-    state_ref,             # SMEM (2,): slot of this group; its DMAs started
-    acc_ref,               # (H, g, d) f32
-    m_ref, l_ref,          # (H, g, 1) f32
-    *, scale: float, blk: int, pps: int, nb: int,
+    *refs,
+    scale: float, blk: int, pps: int, nb: int, append: bool,
 ):
+  """refs: [k_new, v_new (1, H, 1, d) when `append`]; k_hbm, v_hbm
+  (P+1, L, H, blk, d), the pool left in HBM; out (1, H, g, d) f32;
+  [k_out, v_out, the pools aliased in place, when `append`]; k_buf, v_buf
+  (2, H, pps*blk, d), two slots of fetched pages; sems DMA (2, 3), [K or V,
+  slot or 2 for the write-back]; state SMEM (2,), this group's slot and
+  whether its DMAs started; acc (H, g, d), m, l (H, g, 1) f32."""
+  if append:
+    (k_new_ref, v_new_ref, k_hbm, v_hbm, out_ref, k_out, v_out,
+     *scratch) = refs
+  else:
+    k_hbm, v_hbm, out_ref, *scratch = refs
+  k_buf, v_buf, sems, state_ref, acc_ref, m_ref, l_ref = scratch
   b = pl.program_id(0)
   j = pl.program_id(1)
   n_rows = pl.num_programs(0)
@@ -316,8 +334,36 @@ def _paged_flash_decode_kernel(
     state_ref[0] = 1 - slot
     state_ref[1] = (row < n_rows).astype(jnp.int32)
     for_each_page(b, j, slot, lambda cp: cp.wait())
+    if append:
+      # the new row, at length - 1, lies in the row's last live page
+      pos = length_ref[b] - 1
+      page = jnp.minimum(pos // blk, nb - 1)
+      holds_new = page < (j + 1) * pps
+      rows = pl.ds(pl.multiple_of((page - j * pps) * blk, blk), blk)
+
+      def write_back(act):
+        for n, (buf, pool) in enumerate(((k_buf, k_out), (v_buf, v_out))):
+          act(pltpu.make_async_copy(
+              buf.at[slot, :, rows],
+              pool.at[tables_ref[b * nb + page], layer_ref[0]],
+              sems.at[n, 2]))
+
+      @pl.when(holds_new)
+      def _insert():
+        # replace the row in the fetched page, then send the whole page
+        # back: a page's rows share 32-bit words in HBM, so one row alone
+        # cannot be written; rows past the length go back as they came
+        at = jax.lax.broadcasted_iota(jnp.int32, k_buf.shape[1:2] + (blk,)
+                                      + k_buf.shape[3:], 1) == pos % blk
+        for new_ref, buf in ((k_new_ref, k_buf), (v_new_ref, v_buf)):
+          buf[slot, :, rows] = jnp.where(at, new_ref[0], buf[slot, :, rows])
+        write_back(lambda cp: cp.start())
+
     _accumulate_group(q_ref[0], k_buf[slot], v_buf[slot], j * pps * blk,
                       length_ref[b], scale, acc_ref, m_ref, l_ref)
+    if append:
+      # before the slot is refilled and before the kernel ends
+      pl.when(holds_new)(lambda: write_back(lambda cp: cp.wait()))
 
   @pl.when(j == pl.num_programs(1) - 1)
   def _done():
@@ -364,8 +410,16 @@ def paged_flash_decode_kernel(
     length: jax.Array,     # (B,) int32 — valid tokens per row
     scale: float,
     interpret: bool,
-) -> jax.Array:
-  """Block-table-native flash decode over pooled K/V: (B, H, g, d) f32."""
+    new: Optional[Tuple[jax.Array, jax.Array]] = None,
+):
+  """Block-table-native flash decode over pooled K/V: (B, H, g, d) f32.
+
+  With ``new`` = (k_new, v_new), each (B, H, d), row ``length - 1`` of each
+  table (its page clamped to the table) is first set to them, and
+  (out, k_pool, v_pool) is returned.  Hand-DMA head dims write the row
+  inside the kernel, pools aliased in place; narrower ones scatter it in
+  XLA before the call.
+  """
   n_rows, h, g, d = q.shape
   blk = k_pool.shape[3]
   if k_pool.shape[2] != h:
@@ -374,22 +428,46 @@ def paged_flash_decode_kernel(
   pps = pages_per_step(nb, h, g, blk, d, k_pool.dtype.itemsize)
   grid = (n_rows, pl.cdiv(nb, pps))
   by_hand = d % LANES == 0
+  append = new is not None and by_hand
   with timing.span("kernel.paged_flash_decode", pps=pps, grid=grid,
-                   blk=blk, dma="manual" if by_hand else "pipelined"):
+                   blk=blk, dma="manual" if by_hand else "pipelined",
+                   append="none" if new is None else
+                   "kernel" if append else "scatter"):
     pass
+  if new is not None:
+    k_new, v_new = (x.astype(k_pool.dtype) for x in new)
+  if new is not None and not append:
+    pos = length - 1
+    pids = tables[jnp.arange(n_rows), jnp.minimum(pos // blk, nb - 1)]
+    k_pool = k_pool.at[pids, layer[0], :, pos % blk].set(k_new)
+    v_pool = v_pool.at[pids, layer[0], :, pos % blk].set(v_new)
   row = pl.BlockSpec((1, h, g, d), lambda b, j, tbl, lyr, L: (b, 0, 0, 0))
+  out_shape = jax.ShapeDtypeStruct((n_rows, h, g, d), jnp.float32)
   heads = [pltpu.VMEM((h, g, d), jnp.float32),
            pltpu.VMEM((h, g, 1), jnp.float32),
            pltpu.VMEM((h, g, 1), jnp.float32)]
+  aliases = {}
   if by_hand:
-    kernel = functools.partial(
-        _paged_flash_decode_kernel, scale=scale, blk=blk, pps=pps, nb=nb)
+    kernel = functools.partial(_paged_flash_decode_kernel, scale=scale,
+                               blk=blk, pps=pps, nb=nb, append=append)
     pools = [pl.BlockSpec(memory_space=pl.ANY)] * 2
     scratch = [pltpu.VMEM((2, h, pps * blk, d), k_pool.dtype),
                pltpu.VMEM((2, h, pps * blk, d), v_pool.dtype),
-               pltpu.SemaphoreType.DMA((2, 2)),
+               pltpu.SemaphoreType.DMA((2, 3)),
                pltpu.SMEM((2,), jnp.int32)] + heads
     operands = (k_pool, v_pool)
+    out_specs = row
+    if append:
+      # k_new, v_new ride as (1, H, 1, d) blocks: a row broadcast over a
+      # page's rows; the pools, operands 6 and 7 counting the three
+      # prefetched scalars, come back as outputs 1 and 2
+      one = pl.BlockSpec((1, h, 1, d), lambda b, j, tbl, lyr, L: (b, 0, 0, 0))
+      pools = [one, one] + pools
+      operands = (k_new[:, :, None], v_new[:, :, None]) + operands
+      out_specs = [row] + [pl.BlockSpec(memory_space=pl.ANY)] * 2
+      out_shape = (out_shape, jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype))
+      aliases = {6: 1, 7: 2}
   else:
     # one pipelined input per page of the group; an entry past the row's
     # length maps to its last live page, never to the trash block
@@ -403,21 +481,26 @@ def paged_flash_decode_kernel(
     pools = [page(i) for i in range(pps)] * 2
     scratch = heads
     operands = (k_pool,) * pps + (v_pool,) * pps
-  return pl.pallas_call(
+    out_specs = row
+  result = pl.pallas_call(
       kernel,
       grid_spec=pltpu.PrefetchScalarGridSpec(
           num_scalar_prefetch=3,
           grid=grid,
           in_specs=[row] + pools,
-          out_specs=row,
+          out_specs=out_specs,
           scratch_shapes=scratch,
       ),
-      out_shape=jax.ShapeDtypeStruct((n_rows, h, g, d), jnp.float32),
+      out_shape=out_shape,
+      input_output_aliases=aliases,
       compiler_params=pltpu.CompilerParams(
           dimension_semantics=("arbitrary", "arbitrary")),
       interpret=interpret,
       name="paged_flash_decode",
   )(tables.reshape(-1), layer, length, q, *operands)
+  if new is None or append:
+    return result
+  return result, k_pool, v_pool
 
 
 def _packed_paged_flash_decode_kernel(

@@ -290,9 +290,11 @@ class Model:
 
     `resident_leaves` is the flattened per-layer policy state (paged leaves
     None) with leading layer axis; `pool_leaves` the physical pools
-    (P+1, L, ..., block, ...) shared across the layer scan (carried, updated
-    functionally with single-row writes); `tables` the (B, nb) per-slot block
-    tables.  The layer counter rides the carry so each layer's kernel call
+    (P+1, L, ..., block, ...) shared across the layer scan (carried; each
+    layer's policy step writes its new rows into them, for the exact policy
+    at lane-wide head dims by the kernel's whole-page write-back in place);
+    `tables` the (B, nb) per-slot block tables.  The layer counter rides the
+    carry so each layer's kernel call
     addresses its own pool plane through the scalar-prefetched index maps —
     the pool is never sliced, gathered, or densified.  Dense/MoE attention
     families only (the ones the serve engine admits).

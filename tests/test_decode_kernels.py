@@ -16,6 +16,7 @@ Three layers of guarantee, matching the PR 5 numerics contract:
      forced spill/fetch on the tiered runs.
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +34,7 @@ from repro.core import kv_cache as kvc
 from repro.core import pq as pqlib
 from repro.core import pq_attention as pqa
 from repro.kernels import ops, ref
+from repro.kernels import paged_flash_decode as pfd
 from repro.launch.engine import ServeEngine
 
 
@@ -208,6 +210,74 @@ def test_paged_flash_decode_matches_exact_oracle(g, d, blk, nb, seed):
 def test_paged_flash_decode_matches_exact_oracle_at(g, d, blk, nb, h,
                                                      lengths):
   _check_paged_flash_decode(g, d, blk, nb, h, lengths, seed=len(lengths))
+
+
+def _scatter_then_attend(k_pool, v_pool, layer, tables, q, k_new, v_new,
+                         lengths, scale):
+  """The exact paged step as an XLA row scatter, then the read kernel."""
+  b, hq, d = q.shape
+  h, blk = k_pool.shape[2], k_pool.shape[3]
+  pids = tables[jnp.arange(b), lengths // blk]
+  k_pool = k_pool.at[pids, layer, :, lengths % blk].set(k_new)
+  v_pool = v_pool.at[pids, layer, :, lengths % blk].set(v_new)
+  out = ops.paged_flash_decode(q.reshape(b, h, hq // h, d), k_pool, v_pool,
+                               tables, layer, lengths + 1, scale)
+  return out.reshape(b, hq, d), k_pool, v_pool
+
+
+# yi-34b-exact's heads (h=8, g=7, d=128) over 18 pages of 16: a group is
+# pps = 16 pages, so 255 / 256 straddle the group boundary
+@pytest.mark.parametrize("lengths", [
+    pytest.param([32, 100], id="page-start"),
+    pytest.param([37, 5], id="mid-page"),
+    pytest.param([15, 47], id="page-last-row"),
+    pytest.param([255, 256], id="group-boundary"),
+    pytest.param([18 * 16 - 1, 1], id="table-last-row"),
+    pytest.param([0, 130], id="trash-row"),
+])
+def test_exact_paged_step_writes_row_like_scatter(lengths):
+  """The exact paged step, whose kernel writes the new row by whole-page
+  write-back into pools aliased in place, against an XLA scatter then
+  attention: output and both pools (every block but the trash block, NaN
+  rows past the lengths included) bit-identical, over a 2-layer scan in
+  which each layer writes its own plane."""
+  h, g, d, blk, nb, layers = 8, 7, 128, 16, 18, 2
+  assert pfd.pages_per_step(nb, h, g, blk, d, 2) == 16
+  rng = np.random.default_rng(sum(lengths))
+  b = len(lengths)
+  lengths = np.asarray(lengths, np.int32)
+  trash = b * nb
+  tables = rng.permutation(trash).reshape(b, nb).astype(np.int32)
+  k_pool = rng.normal(size=(trash + 1, layers, h, blk, d))
+  v_pool = rng.normal(size=(trash + 1, layers, h, blk, d))
+  for i, n in enumerate(lengths):
+    # the new row's page is allocated; a row of length 0 has none yet
+    tables[i, n // blk + 1 if n else 0:] = trash
+    for pool in (k_pool, v_pool):
+      pool[tables[i, n // blk], :, :, n % blk:] = np.nan
+  bf16 = jnp.bfloat16
+  k_pool, v_pool = jnp.asarray(k_pool, bf16), jnp.asarray(v_pool, bf16)
+  q = jnp.asarray(rng.normal(size=(layers, b, h * g, d)), bf16)
+  k_new = jnp.asarray(rng.normal(size=(layers, b, h, d)), bf16)
+  v_new = jnp.asarray(rng.normal(size=(layers, b, h, d)), bf16)
+  tables, lengths = jnp.asarray(tables), jnp.asarray(lengths)
+
+  def scan(step):
+    def layer(carry, x):
+      i, kp, vp = carry
+      out, kp, vp = step(kp, vp, i, tables, *x, lengths, d ** -0.5)
+      return (i + 1, kp, vp), out
+    (_, kp, vp), out = jax.lax.scan(
+        layer, (jnp.int32(0), k_pool, v_pool), (q, k_new, v_new))
+    return out, kp, vp
+
+  want = scan(_scatter_then_attend)
+  got = scan(functools.partial(kvc.exact_cache_paged_step, interpret=True))
+  assert np.isfinite(np.asarray(got[0], np.float32)).all()
+  np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+  for new_pool, old_pool in zip(got[1:], want[1:]):
+    np.testing.assert_array_equal(np.asarray(new_pool[:trash], np.float32),
+                                  np.asarray(old_pool[:trash], np.float32))
 
 
 def test_dense_flash_decode_matches_exact_oracle():

@@ -7,17 +7,22 @@ widths — g=8 query heads per kv head, head dim 64, PQ m=32 / K=512
 what these do: Mosaic refuses shape casts, gathers and loads it does not
 lower, DMA slices narrower than a lane tile, and fast-memory budgets it
 cannot meet.  The exact paged kernel also compiles at yi-34b-exact's
-shapes (head dim 128, g=7), where it fetches pool pages by hand.
+shapes (head dim 128, g=7), where it fetches pool pages by hand, and the
+exact policy's paged step compiles in a 4-layer scan there without a
+whole-pool copy or scatter: the kernel writes the new row itself.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, so the worker that runs this file
 loads it and the others never try.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import kv_cache as kvc
 from repro.kernels import paged_flash_decode as pfd
 from repro.kernels import pq_decode as pqd
 
@@ -115,3 +120,46 @@ def test_decode_kernel_compiles_for_v5e(one_chip, lower):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
   compiled = lower(arg).compile()
   assert 'custom_call_target="tpu_custom_call"' in compiled.as_text()
+
+
+@pytest.mark.parametrize("d", [
+    pytest.param(128, id="yi34b-kernel-writes"),
+    pytest.param(64, id="d64-scatter"),
+])
+def test_exact_paged_step_scan_compiles_for_v5e(one_chip, d):
+  """yi-34b-exact's decode: 4 layers scanned over one donated pool pair,
+  16 rows of 256-entry tables, 8 kv heads at g=7.  At head dim 128 the
+  kernel writes each new row by whole-page DMA into pools aliased in
+  place, so no op of the program makes a pool-sized copy, scatter or
+  fusion (a bf16 pool's pages share 32-bit words between row pairs: an
+  XLA row scatter re-lays the whole pool out, 12 copies a step).  At 64
+  the row is scattered in XLA; that path need only compile."""
+  pool, layers, heads, g, rows, nb = 4097, 4, 8, 7, 16, 256
+
+  def arg(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+  def step(k_pool, v_pool, tables, q, k_new, v_new, lengths):
+    def layer(carry, x):
+      i, kp, vp = carry
+      out, kp, vp = kvc.exact_cache_paged_step(
+          kp, vp, i, tables, *x, lengths, d ** -0.5, interpret=False)
+      return (i + 1, kp, vp), out
+    (_, k_pool, v_pool), out = jax.lax.scan(
+        layer, (jnp.int32(0), k_pool, v_pool), (q, k_new, v_new))
+    return out, k_pool, v_pool
+
+  pools = arg((pool, layers, heads, BLOCK, d), jnp.bfloat16)
+  compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+      pools, pools, arg((rows, nb), jnp.int32),
+      arg((layers, rows, heads * g, d), jnp.bfloat16),
+      arg((layers, rows, heads, d), jnp.bfloat16),
+      arg((layers, rows, heads, d), jnp.bfloat16),
+      arg((rows,), jnp.int32)).compile()
+  hlo = compiled.as_text()
+  assert 'custom_call_target="tpu_custom_call"' in hlo
+  if d % pfd.LANES == 0:
+    shape = re.escape(f"bf16[{pool},{layers},{heads},{BLOCK},{d}]")
+    writes = re.findall(
+        rf"%\S+ = {shape}\{{[^}}]*\}} (?:copy|scatter|fusion)\(", hlo)
+    assert not writes, writes
